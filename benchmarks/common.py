@@ -4,10 +4,19 @@ plus the shared metrics sink every fig script's rows land in
 from __future__ import annotations
 
 import os
+import subprocess
+import sys
+import textwrap
 import time
 
 import jax
 import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# fake host devices exist only on XLA:CPU; children that use them are pinned
+# there, so on a machine with a chip none of them grabs or waits on it (the
+# parent may hold the chip).  Their rows record this platform.
+FAKE_DEVICE_PLATFORM = "cpu"
 
 _RESULTS_DIR: str | None = None
 _SINK = None
@@ -39,6 +48,22 @@ def record(rec: dict) -> None:
     s = _sink()
     if s is not None:
         s.emit(rec)
+
+
+def run_on_fake_devices(script: str, devices: int,
+                        timeout: int = 560) -> str:
+    """Run a child Python script on ``devices`` fake CPU devices; returns
+    its stdout (raises with its stderr when it fails)."""
+    env = dict(os.environ)
+    env.update(PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS=FAKE_DEVICE_PLATFORM,
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
+                         capture_output=True, text=True, env=env,
+                         timeout=timeout)
+    if out.returncode != 0:
+        raise RuntimeError(out.stderr[-2000:])
+    return out.stdout
 
 
 def smoke_mode() -> bool:

@@ -15,15 +15,12 @@ the XLA two-pass path; the HBM-traffic win shows on real TPUs.  The
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import emit, timeit
+from benchmarks.common import (FAKE_DEVICE_PLATFORM, emit,
+                               run_on_fake_devices, timeit)
 
 T, DM, DH, E, K = 256, 64, 128, 8, 2
 W = 4  # expert-parallel ranks for the distributed wire-evidence rows
@@ -33,8 +30,6 @@ W = 4  # expert-parallel ranks for the distributed wire-evidence rows
 # *forward* program's optimized-HLO exchange bytes (the counter models the
 # forward exchange; the backward adds its mirror image on top).
 _DIST_SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={w}"
 import json
 import numpy as np
 import jax, jax.numpy as jnp
@@ -44,7 +39,8 @@ from repro.launch.roofline import collective_bytes
 
 w, E, T, DM, DH, K = {w}, {e}, {t}, {dm}, {dh}, {k}
 x = jax.random.normal(jax.random.PRNGKey(1), (T, DM))
-mesh = jax.make_mesh((1, w), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(1, w)
 rows = []
 for dispatch in ("capacity", "ragged"):
     cfg = MoEConfig(num_experts=E, top_k=K, d_expert_hidden=DH,
@@ -86,7 +82,7 @@ for dispatch in ("capacity", "ragged"):
 
 # two-level ragged exchange on the (data, node, model) mesh: same fwd+bwd
 # step, wire counter split intra/inter and checked against the fwd HLO
-mesh_h = jax.make_mesh((1, 2, w // 2), ("data", "node", "model"))
+mesh_h = make_local_mesh(1, w // 2, node=2)
 cfg = MoEConfig(num_experts=E, top_k=K, d_expert_hidden=DH,
                 dispatch="ragged", capacity_factor=2.0)
 params = fmoe.fmoe_init(jax.random.PRNGKey(0), DM, cfg)
@@ -184,20 +180,13 @@ def run(quick: bool = False) -> list[dict]:
 
 
 def _run_dist(quick: bool) -> list[dict]:
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    env.pop("XLA_FLAGS", None)
     t = T // 2 if quick else T
     script = _DIST_SCRIPT.format(w=W, e=E, t=t, dm=DM, dh=DH, k=K)
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
-                         capture_output=True, text=True, env=env, timeout=560)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    rows = json.loads(out.stdout.strip().split("RESULTJSON ")[1].splitlines()[0])
+    out = run_on_fake_devices(script, W)
+    rows = json.loads(out.strip().split("RESULTJSON ")[1].splitlines()[0])
     for r in rows:
         r.update(impl="einsum", distributed=True, ranks=W,
-                 backend=jax.default_backend())
+                 backend=FAKE_DEVICE_PLATFORM)
         split = ("" if "wire_bytes_inter" not in r else
                  f" intra={r['wire_bytes_intra']:.0f}"
                  f" inter={r['wire_bytes_inter']:.0f}")

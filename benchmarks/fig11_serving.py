@@ -22,19 +22,15 @@ latency, decode ticks, live replans.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
+import json
 
-from benchmarks.common import record, smoke_mode
+from benchmarks.common import (FAKE_DEVICE_PLATFORM, record,
+                               run_on_fake_devices, smoke_mode)
 
 W = 4  # fake host devices -> 1x4 mesh
 REPLAN_EVERY = 8
 
 _SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={w}"
 import dataclasses, json, time
 import numpy as np
 import jax, jax.numpy as jnp
@@ -109,24 +105,14 @@ print("RESULT " + json.dumps(rows))
 
 
 def run(quick: bool = False) -> list[dict]:
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    env.pop("XLA_FLAGS", None)
     # the replan arm needs enough decode slots that the modeled a2a savings
     # beat the shadow-weight cost (see the controller's cost model); smoke
     # only proves the three modes run and continuous beats static
     slots, nreq = (8, 24) if (quick or smoke_mode()) else (32, 120)
     script = _SCRIPT.format(w=W, mw=W, slots=slots, nreq=nreq,
                             every=REPLAN_EVERY)
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
-                         capture_output=True, text=True, env=env, timeout=560)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    import json
-
-    import jax
-    rows = json.loads(out.stdout.strip().split("RESULT ")[1])
+    rows = json.loads(run_on_fake_devices(script, W).strip()
+                      .split("RESULT ")[1])
     static, cont = rows[0], rows[1]
     if cont["tok_s"] <= static["tok_s"]:
         raise RuntimeError(
@@ -135,7 +121,7 @@ def run(quick: bool = False) -> list[dict]:
             f"(ticks {cont['ticks']} vs {static['ticks']})")
     for r in rows:
         r["slots"] = slots
-        r["backend"] = jax.default_backend()
+        r["backend"] = FAKE_DEVICE_PLATFORM
         record({"bench": "fig11", **r})
         print(f"fig11,{r['mode']},{r['tok_s']:.1f} tok/s,"
               f"p50={r['p50_ms']:.1f}ms p99={r['p99_ms']:.1f}ms "

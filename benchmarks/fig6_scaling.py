@@ -10,19 +10,12 @@ reports sub-linear scaling).
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
-
-from benchmarks.common import emit
+from benchmarks.common import FAKE_DEVICE_PLATFORM, emit, run_on_fake_devices
 
 WORKERS = [1, 2, 4, 8]
 NB, DM, DH, K, NE = 1024, 128, 512, 2, 4  # paper: ne=4 experts per worker
 
 _SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={w}"
 import time, jax, jax.numpy as jnp
 from repro.configs.base import MoEConfig
 from repro.core import fmoe
@@ -35,7 +28,8 @@ if w == 1:
     fn = jax.jit(lambda p, x: fmoe.fmoe_apply(p, x, cfg)[0])
     ctx = None
 else:
-    mesh = jax.make_mesh((1, w), ("data", "model"))
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh(1, w)
     dist = fmoe.DistConfig(mesh, ("data", "model"))
     fn = jax.jit(lambda p, x: fmoe.fmoe_apply(p, x, cfg, dist=dist)[0])
     ctx = mesh
@@ -59,19 +53,12 @@ print(f"RESULT {{dt*1e6:.1f}} {{flops/dt/1e9:.2f}}")
 
 def run(quick: bool = False) -> list[dict]:
     rows = []
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for w in (WORKERS[:3] if quick else WORKERS):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.path.join(root, "src")
-        env.pop("XLA_FLAGS", None)
         script = _SCRIPT.format(w=w, nb=NB, dm=DM, dh=DH, k=K, ne=NE)
-        out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
-                             capture_output=True, text=True, env=env,
-                             timeout=560)
-        if out.returncode != 0:
-            raise RuntimeError(out.stderr[-2000:])
-        us, gflops = out.stdout.strip().split("RESULT ")[1].split()
+        out = run_on_fake_devices(script, w)
+        us, gflops = out.strip().split("RESULT ")[1].split()
         emit(f"fig6_workers{w}", float(us), f"{gflops}GFLOP/s "
              f"E={NE * w}")
-        rows.append({"workers": w, "us": float(us), "gflops": float(gflops)})
+        rows.append({"workers": w, "us": float(us), "gflops": float(gflops),
+                     "backend": FAKE_DEVICE_PLATFORM})
     return rows

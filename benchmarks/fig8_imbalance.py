@@ -13,20 +13,13 @@ observed drop fraction, shadow count and capacity scale.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
-
-from benchmarks.common import emit
+from benchmarks.common import FAKE_DEVICE_PLATFORM, emit, run_on_fake_devices
 
 W = 4  # expert-parallel ranks (fake devices)
 NB, DM, DH, K, E = 4096, 64, 128, 2, 16
 ZIPF_A = 1.2
 
 _SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={w}"
 import time
 import numpy as np
 import jax, jax.numpy as jnp
@@ -50,7 +43,8 @@ x = jnp.asarray(centers[z] + 0.3 * rng.normal(size=(NB, DM)).astype(np.float32))
 params = fmoe.fmoe_init(jax.random.PRNGKey(0), DM, cfg)
 params["router"]["w"] = jnp.asarray(centers.T * 4.0)
 
-mesh = jax.make_mesh((1, w), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(1, w)
 dist0 = fmoe.DistConfig(mesh, ("data", "model"))
 
 def bench(dist, prm):
@@ -83,30 +77,23 @@ print(f"RESULT {{us0:.1f}} {{us1:.1f}} {{base_elems}} {{spec.a2a_elems(DM)}} "
 
 
 def run(quick: bool = False) -> list[dict]:
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    env.pop("XLA_FLAGS", None)
     # quick halves tokens AND experts' hidden dim together: shadowing pays
     # when a2a slice bytes (C*d) beat weight-sync bytes (~3*d*h), so scale
     # both or the small regime stops demonstrating the mechanism
     nb, dh = (NB // 2, DH // 2) if quick else (NB, DH)
     script = _SCRIPT.format(w=W, e=E, nb=nb, dm=DM, dh=dh, k=K,
                             zipf_a=ZIPF_A)
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
-                         capture_output=True, text=True, env=env, timeout=560)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    vals = out.stdout.strip().split("RESULT ")[1].split()
+    vals = run_on_fake_devices(script, W).strip().split("RESULT ")[1].split()
     us0, us1 = float(vals[0]), float(vals[1])
     elems0, elems1 = int(vals[2]), int(vals[3])
-    import jax  # backend tag gates cost-model calibration (placement/calibrate)
     row = {
         "us_off": us0, "us_on": us1,
         "a2a_elems_off": elems0, "a2a_elems_on": elems1,
         "drop_off": float(vals[4]), "drop_on": float(vals[5]),
         "num_shadow": int(vals[6]), "capacity_scale": float(vals[7]),
-        "imbalance": float(vals[8]), "backend": jax.default_backend(),
+        "imbalance": float(vals[8]),
+        # the backend tag gates cost-model calibration (placement/calibrate)
+        "backend": FAKE_DEVICE_PLATFORM,
     }
     emit("fig8_placement_off", us0,
          f"a2a_elems={elems0} drop={row['drop_off']:.3f} imb={row['imbalance']:.2f}")

@@ -20,12 +20,9 @@ schedule's *structure*; the win shows up on real ICI links.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
+import json
 
-from benchmarks.common import emit
+from benchmarks.common import FAKE_DEVICE_PLATFORM, emit, run_on_fake_devices
 
 W = 4  # expert-parallel ranks (fake devices)
 NB, DM, DH, K, E = 4096, 64, 128, 2, 16
@@ -33,8 +30,6 @@ ZIPF_A = 1.2
 CHUNKS = 4
 
 _SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={w}"
 import time
 import numpy as np
 import jax, jax.numpy as jnp
@@ -57,7 +52,8 @@ x = jnp.asarray(centers[z] + 0.3 * rng.normal(size=(NB, DM)).astype(np.float32))
 params = fmoe.fmoe_init(jax.random.PRNGKey(0), DM, cfg)
 params["router"]["w"] = jnp.asarray(centers.T * 4.0)
 
-mesh = jax.make_mesh((1, w), ("data", "model"))
+from repro.launch.mesh import make_local_mesh
+mesh = make_local_mesh(1, w)
 dist0 = fmoe.DistConfig(mesh, ("data", "model"))
 dist1 = fmoe.DistConfig(mesh, ("data", "model"), overlap_chunks=CH)
 dist_b = fmoe.DistConfig(mesh, ("data", "model"), wire_dtype="bf16")
@@ -114,7 +110,7 @@ from repro.core.monitor import LoadMonitor
 cfg_r = MoEConfig(num_experts=E, top_k=K, d_expert_hidden=DH,
                   dispatch="ragged", capacity_factor=2.0)
 n_nodes, n_inner = 2, w // 2
-mesh_h = jax.make_mesh((1, n_nodes, n_inner), ("data", "node", "model"))
+mesh_h = make_local_mesh(1, n_inner, node=n_nodes)
 AXH = ("data", "node", "model")
 zr = np.empty(E, np.int64)  # expert -> interleaved Zipf rank
 zr[:E // 2], zr[E // 2:] = 2 * np.arange(E // 2), 2 * np.arange(E // 2) + 1
@@ -194,21 +190,11 @@ print("RESULTJSON " + json.dumps({{
 
 
 def run(quick: bool = False) -> list[dict]:
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    env.pop("XLA_FLAGS", None)
     nb = NB // 2 if quick else NB
     script = _SCRIPT.format(w=W, e=E, nb=nb, dm=DM, dh=DH, k=K,
                             zipf_a=ZIPF_A, chunks=CHUNKS)
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
-                         capture_output=True, text=True, env=env, timeout=560)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    import json
-
-    import jax  # backend tag gates cost-model calibration (placement/calibrate)
-    vals = json.loads(out.stdout.strip().split("RESULTJSON ")[1].splitlines()[0])
+    out = run_on_fake_devices(script, W)
+    vals = json.loads(out.strip().split("RESULTJSON ")[1].splitlines()[0])
     row = {
         "us_serial": vals["us0"], "us_pipelined": vals["us1"],
         "n_chunks": vals["ch"], "hlo_all_to_all_serial": vals["a2a0"],
@@ -225,7 +211,8 @@ def run(quick: bool = False) -> list[dict]:
         # two-level ragged exchange on the (1, 2, 2) node mesh under the
         # interleaved Zipf skew, with LoadMonitor-calibrated bounds
         "hier": vals["hier"],
-        "backend": jax.default_backend(),
+        # the backend tag gates cost-model calibration (placement/calibrate)
+        "backend": FAKE_DEVICE_PLATFORM,
     }
     emit("fig9_serial", row["us_serial"],
          f"all_to_all_ops={row['hlo_all_to_all_serial']} "

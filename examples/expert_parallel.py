@@ -18,7 +18,8 @@ from repro.core.naive import moe_loop_masked
 
 
 def main() -> None:
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh(2, 4)
     cfg = MoEConfig(num_experts=8, top_k=2, d_expert_hidden=256,
                     capacity_factor=2.0)
     params = fmoe.fmoe_init(jax.random.PRNGKey(0), 128, cfg)

@@ -5,8 +5,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def exchange_counts(counts: jax.Array, axis: str) -> jax.Array:
     """Fig 2 step 1: exchange per-expert token counts over the expert axis.
@@ -14,13 +12,13 @@ def exchange_counts(counts: jax.Array, axis: str) -> jax.Array:
     counts: (E,) local assignment counts, E = mp * E_local.
     returns (mp, E_local): incoming token counts per source rank.
     """
-    mp = compat.axis_size(axis)
+    mp = jax.lax.axis_size(axis)
     return jax.lax.all_to_all(counts.reshape(mp, -1), axis, 0, 0, tiled=True)
 
 
 def exchange_tokens(buf: jax.Array, axis: str) -> jax.Array:
     """Fig 2 step 2: payload all-to-all.  buf (E, C, d) -> (E_local, mp*C, d)."""
-    mp = compat.axis_size(axis)
+    mp = jax.lax.axis_size(axis)
     E, C, d = buf.shape
     buf = buf.reshape(mp, E // mp, C, d)
     buf = jax.lax.all_to_all(buf, axis, 0, 0, tiled=True)
@@ -29,12 +27,53 @@ def exchange_tokens(buf: jax.Array, axis: str) -> jax.Array:
 
 def return_tokens(out: jax.Array, axis: str) -> jax.Array:
     """Inverse of :func:`exchange_tokens`: (E_local, mp*C, d) -> (E, C, d)."""
-    mp = compat.axis_size(axis)
+    mp = jax.lax.axis_size(axis)
     E_local, n, d = out.shape
     C = n // mp
     out = out.reshape(E_local, mp, C, d).transpose(1, 0, 2, 3)
     out = jax.lax.all_to_all(out, axis, 0, 0, tiled=True)
     return out.reshape(E_local * mp, C, d)
+
+
+def native_ragged_all_to_all() -> bool:
+    """True when the devices of the mesh being traced implement XLA's
+    ragged-all-to-all (the TPU does; XLA:CPU leaves it unimplemented).
+
+    Read from the abstract mesh's device kind, so a compile for a described
+    TPU takes the native branch even in a process whose default backend is
+    the CPU; outside a mesh the default backend decides.
+    """
+    dev = jax.sharding.get_abstract_mesh().abstract_device
+    kind = dev.device_kind if dev is not None else jax.default_backend()
+    return kind.lower() != "cpu"
+
+
+def ragged_all_to_all_shards(send, send_sizes, recv_sizes, axis):
+    """Exchange ``(mp, bound, ...)`` per-peer shards, valid-prefix ragged.
+
+    ``send[p, :send_sizes[p]]`` are the rows for peer ``p`` (zero padding
+    after); the result holds ``recv[s, :recv_sizes[s]]`` rows from source
+    ``s`` (zero padding after) — i.e. exactly what a dense tiled dim-0
+    all-to-all of the padded shards returns when padding is zeros.
+
+    Where :func:`native_ragged_all_to_all` holds, only the valid prefixes
+    go through ``lax.ragged_all_to_all``; otherwise the dense bounded-shard
+    all-to-all moves the full static buffer.  Both branches return
+    bit-identical arrays, so callers never see which transport ran.
+    """
+    if not native_ragged_all_to_all():
+        return jax.lax.all_to_all(send, axis, 0, 0, tiled=True)
+    mp, bound = send.shape[0], send.shape[1]
+    flat = send.reshape(mp * bound, *send.shape[2:])
+    offs = jnp.arange(mp, dtype=jnp.int32) * bound
+    # my segment for peer p starts at p*bound locally and must land at slot
+    # (my_rank * bound) on peer p — the same place the dense exchange puts it
+    my = jax.lax.axis_index(axis).astype(jnp.int32) * bound
+    out = jax.lax.ragged_all_to_all(
+        flat, jnp.zeros_like(flat), offs, jnp.asarray(send_sizes, jnp.int32),
+        jnp.full((mp,), my, jnp.int32), jnp.asarray(recv_sizes, jnp.int32),
+        axis_name=axis)
+    return out.reshape(send.shape)
 
 
 def exchange_ragged(send: jax.Array, counts: jax.Array, axis, mp: int, *,
@@ -115,22 +154,23 @@ def exchange_ragged_inter(slim: jax.Array, kept_counts: jax.Array, node_axis,
     slim: (n_nodes, inter_bound, d) aggregated per-node shards (only
     truly-needed rows + tail padding); kept_counts: (n_nodes, n_inner,
     E_local) full per-source-rank granularity, so the receiver can rebuild
-    the exact flat-path compaction.  When the installed jax has the native
-    ``lax.ragged_all_to_all`` and the leg is not ppermute-decomposed, the
-    payload travels through it (only valid prefixes cross the wire);
-    otherwise the bounded-shard exchange moves the static buffer.  Returns
-    ``(recv, incoming, fill_out)`` like :func:`exchange_ragged`.
+    the exact flat-path compaction.  Unless the leg is ppermute-decomposed,
+    the payload goes through :func:`ragged_all_to_all_shards` (only valid
+    prefixes cross the wire where the devices implement the native
+    primitive); otherwise the bounded-shard exchange moves the static
+    buffer.  Returns ``(recv, incoming, fill_out)`` like
+    :func:`exchange_ragged`.
     """
     from repro.core import pipeline
 
     incoming = pipeline.counts_all_to_all(
         kept_counts.reshape(n_nodes, -1), node_axis, n_nodes,
         decompose=n_chunks > 1).reshape(kept_counts.shape)
-    if n_chunks <= 1 and compat.has_ragged_all_to_all():
+    if n_chunks <= 1:
         orig = slim.dtype
         w, wd = pipeline._to_wire(slim, orig, wire_dtype)
         recv = pipeline._from_wire(
-            compat.ragged_all_to_all_shards(
+            ragged_all_to_all_shards(
                 w, kept_counts.sum(axis=(1, 2)), incoming.sum(axis=(1, 2)),
                 node_axis), orig, wd)
         return recv, incoming, (fill_fn() if fill_fn is not None else None)
@@ -147,11 +187,11 @@ def return_ragged_inter(out: jax.Array, kept_counts: jax.Array,
     roles: each rank returns what it received, gets back what it sent)."""
     from repro.core import pipeline
 
-    if n_chunks <= 1 and compat.has_ragged_all_to_all():
+    if n_chunks <= 1:
         orig = out.dtype
         w, wd = pipeline._to_wire(out, orig, wire_dtype)
         return pipeline._from_wire(
-            compat.ragged_all_to_all_shards(
+            ragged_all_to_all_shards(
                 w, incoming.sum(axis=(1, 2)), kept_counts.sum(axis=(1, 2)),
                 node_axis), orig, wd)
     return pipeline.chunked_all_to_all(out, node_axis, n_nodes, n_chunks,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import MoEConfig
 from repro.core import dispatch as D
 from repro.core import pipeline
@@ -1300,7 +1299,7 @@ def fmoe_apply(params: dict, x: jax.Array, cfg: MoEConfig, *, act: str = "swiglu
         if has_rng:
             operands.append(rng)
             in_specs.append(P(None))
-        y, metrics = compat.shard_map(
+        y, metrics = jax.shard_map(
             fn, mesh=dist.mesh,
             in_specs=tuple(in_specs),
             out_specs=(tok_spec, mspec),

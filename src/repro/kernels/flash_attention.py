@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -115,7 +114,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, S, H, dv), q.dtype),
             interpret=interpret,
-            compiler_params=compat.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
         )(win, q, k, v)

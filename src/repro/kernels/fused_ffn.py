@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 DEFAULT_BM = 128
 DEFAULT_BH = 512
@@ -145,6 +144,6 @@ def fused_ffn_tiled(x: jax.Array, ws: tuple, wo: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(tile_group, x, *ws, wo)

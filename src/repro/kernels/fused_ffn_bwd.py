@@ -34,8 +34,10 @@ interpreter), and NaN * 0 is still NaN.
 
 VMEM working set (dX): x (bm, K) + dy (bm, N) + weight tiles
 (len(ws)*K*bh + bh*N) + f32 acc (bm, K); dW additionally holds the f32
-output blocks (len(ws)*K*bh + bh*N).  With the defaults (bm=128, bh=512)
-shrink ``bh`` for d_model > 1024 to stay inside the ~16 MiB/core budget.
+output blocks (len(ws)*K*bh + bh*N).  Every block is double-buffered, so
+dW at d_model 1024 with f32 weights and the default bh=512 needs ~19 MiB,
+over the 16 MiB scoped-VMEM default; ``_dw_block_h`` halves dW's hidden
+tile until its working set fits (dW's sums do not depend on bh).
 
 ``repro.kernels.ops`` wires both into ``fused_grouped_ffn``'s custom_vjp
 (padding/unpadding rows via ``pad_to_tiles`` exactly like the forward) and
@@ -50,7 +52,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels import fused_ffn as ff
 
 
@@ -181,6 +182,23 @@ def _common_dims(x, ws, wo, dy, bm, bh):
     return M, K, H, N, E, bh, M // bm, pl.cdiv(H, bh)
 
 
+_VMEM_BUDGET = 16 * 2**20  # Mosaic's default scoped-VMEM limit per core
+
+
+def _dw_block_h(bm: int, K: int, N: int, bh: int, n_w: int, x_bytes: int,
+                w_bytes: int) -> int:
+    """Largest hidden tile, halving from ``bh`` (down to one 128-lane tile),
+    whose dW working set fits ``_VMEM_BUDGET``: double-buffered row, weight
+    and f32 output blocks plus the (bm, bh) f32 recompute intermediates."""
+    def need(b):
+        w_cols = n_w * K * b + b * N
+        blocks = bm * (K + N) * x_bytes + w_cols * w_bytes + w_cols * 4
+        return 2 * blocks + (2 + 2 * n_w) * bm * b * 4
+    while bh > 128 and need(bh) > _VMEM_BUDGET:
+        bh //= 2
+    return bh
+
+
 def _wi_spec(K, bh, index_map):
     return pl.BlockSpec((1, K, bh), index_map)
 
@@ -218,7 +236,7 @@ def fused_ffn_bwd_dx_tiled(x: jax.Array, ws: tuple, wo: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, K), x.dtype),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(tile_group, x, dy, *ws, wo)
 
@@ -238,7 +256,10 @@ def fused_ffn_bwd_dw_tiled(x: jax.Array, ws: tuple, wo: jax.Array,
     """
     ff.check_gating(ws, act)
     gated = len(ws) == 2
-    M, K, H, N, E, bh, n_m, n_h = _common_dims(x, ws, wo, dy, bm, bh)
+    M, K, H, N, E, bh, n_m, _ = _common_dims(x, ws, wo, dy, bm, bh)
+    bh = _dw_block_h(bm, K, N, bh, len(ws), x.dtype.itemsize,
+                     wo.dtype.itemsize)
+    n_h = pl.cdiv(H, bh)
 
     in_specs = [pl.BlockSpec((bm, K), lambda j, i, g: (i, 0)),
                 pl.BlockSpec((bm, N), lambda j, i, g: (i, 0))]
@@ -261,7 +282,7 @@ def fused_ffn_bwd_dw_tiled(x: jax.Array, ws: tuple, wo: jax.Array,
         grid_spec=grid_spec,
         out_shape=tuple(out_shape),
         interpret=interpret,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(tile_group, x, dy, *ws, wo)
     return tuple(outs[:len(ws)]), outs[len(ws)]

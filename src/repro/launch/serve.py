@@ -371,6 +371,8 @@ def main() -> None:
                          "(all routers are deterministic at decode: no rng "
                          "is threaded, so gumbel == topk here)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     scfg = ServeConfig.from_args(args)
     if args.max_len is None:

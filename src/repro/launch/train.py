@@ -225,6 +225,17 @@ def jit_train_step(cfg: ModelConfig, opt: AdamW, mesh, global_batch: int,
     ), pshard, oshard
 
 
+def init_state(cfg: ModelConfig, opt: AdamW, pshard, oshard, *,
+               seed: int = 0):
+    """Params and optimizer state placed under ``jit_train_step``'s
+    shardings.  The optimizer state is made in place, sharded: unsharded,
+    the AdamW moments of a four-chip expert-parallel model would not fit on
+    the one device that ``opt.init`` would otherwise fill."""
+    params = jax.device_put(lm.init_params(jax.random.PRNGKey(seed), cfg),
+                            pshard)
+    return params, jax.jit(opt.init, out_shardings=oshard)(params)
+
+
 # ---------------------------------------------------------------------------
 # Periodic replan-and-migrate hook (placement subsystem, paper §6 follow-on)
 # ---------------------------------------------------------------------------
@@ -518,8 +529,10 @@ def main() -> None:
                          "of host-side spans: train_step, replan, migrate")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import JsonlSink, StepStats, modeled_collective_bytes
     from repro.obs import trace as obs_trace
+    enable_compile_cache()
     sink = JsonlSink(args.metrics_out) if args.metrics_out else None
     if args.trace:
         obs_trace.configure(enabled=True)
@@ -563,9 +576,7 @@ def main() -> None:
         step_fn, pshard, oshard = jit_train_step(
             cfg, opt, mesh, args.batch, args.seq,
             num_microbatches=args.microbatches, opts=opts)
-        params = jax.device_put(lm.init_params(jax.random.PRNGKey(0), cfg),
-                                pshard)
-        opt_state = jax.device_put(opt.init(params), oshard)
+        params, opt_state = init_state(cfg, opt, pshard, oshard)
         if args.replan_every and cfg.moe is not None and m > 1:
             hook = ReplanHook(cfg, opt, mesh, args.batch, args.seq,
                               every=args.replan_every,
@@ -584,7 +595,8 @@ def main() -> None:
         opt_state = opt.init(params)
         step_fn = jax.jit(make_train_step(cfg, opt,
                                           num_microbatches=args.microbatches,
-                                          impl=args.impl))
+                                          impl=args.impl),
+                          donate_argnums=(0, 1))
 
     def modeled_of(fn, p, o, b, s):
         # HLO-derived collective bytes for the StepStats modeled-vs-measured
@@ -663,7 +675,7 @@ def main() -> None:
                 else:
                     step_fn = jax.jit(make_train_step(
                         cfg, opt, num_microbatches=args.microbatches,
-                        impl=args.impl))
+                        impl=args.impl), donate_argnums=(0, 1))
                 obs_events.emit(sink, obs_events.ROUTER_FROZEN, step=step)
                 if sink is not None:
                     modeled = modeled_of(step_fn, params, opt_state, batch,

@@ -74,10 +74,11 @@ def run_cli(argv: list, devices: int = 4, timeout: int = 560, env=None,
 
 def make_mesh(data: int = 2, model: int = 4, node: int = 0):
     """Flat (data, model) mesh, or the (data, node, model) node-major mesh
-    of the two-level hierarchy when ``node`` is given."""
-    if node:
-        return jax.make_mesh((data, node, model), ("data", "node", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+    of the two-level hierarchy when ``node`` is given — the program's own
+    meshes (launch/mesh.make_local_mesh), with the tests' default shape."""
+    from repro.launch.mesh import make_local_mesh
+
+    return make_local_mesh(data, model, node=node or 1)
 
 
 def moe_env(*, num_experts: int = 8, top_k: int = 2, d_hidden: int = 64,

@@ -200,7 +200,8 @@ def test_cache_seq_sharded_decode_matches_single_device():
             outs.append(lg)
         ref = jnp.concatenate(outs, 1)
         # sharded: batch over data, window over model
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(2, 4)
         specs = cache_specs(jax.eval_shape(lambda: lm.init_cache(cfg, B, W)),
                             mesh, B, seq_shard=True)
         flat = jax.tree.leaves(specs, is_leaf=lambda s: isinstance(s, P))
@@ -230,7 +231,8 @@ def test_cross_pod_expert_parallelism_matches_local():
         import dist_utils as du
         from repro.core import fmoe
         env = du.moe_env(num_shared_experts=1)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(2, 2, pod=2)
         y_ref, _ = du.oracle(env)
         dist = fmoe.DistConfig(mesh, ("pod", "data", "model"),
                                expert_axis=("pod", "model"),
@@ -250,9 +252,9 @@ def test_hierarchical_a2a_equals_flat():
     """Beyond-paper 2-hop all-to-all must move the same data as 1-hop."""
     print(du.run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import shard_map
         from repro.core.comm import hierarchical_all_to_all
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(4, 1, pod=2)
         P = jax.sharding.PartitionSpec
         def flat(x):
             return jax.lax.all_to_all(x, ("pod", "data"), 0, 0, tiled=True)
@@ -263,9 +265,9 @@ def test_hierarchical_a2a_equals_flat():
             return y.reshape(8, -1)
         # global (64, 16): local (8, 16) per device = one chunk per peer
         x = jnp.arange(64 * 16, dtype=jnp.float32).reshape(64, 16)
-        f1 = shard_map(flat, mesh=mesh, in_specs=P(("pod", "data"), None),
+        f1 = jax.shard_map(flat, mesh=mesh, in_specs=P(("pod", "data"), None),
                        out_specs=P(("pod", "data"), None), check_vma=False)
-        f2 = shard_map(hier, mesh=mesh, in_specs=P(("pod", "data"), None),
+        f2 = jax.shard_map(hier, mesh=mesh, in_specs=P(("pod", "data"), None),
                        out_specs=P(("pod", "data"), None), check_vma=False)
         with mesh:
             y1, y2 = f1(x), f2(x)

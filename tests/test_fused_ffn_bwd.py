@@ -181,3 +181,19 @@ def test_fused_impl_grads_in_moe_layer(dispatch):
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
                                    atol=2e-4)
+
+
+def test_dw_hidden_tile_fitted_to_vmem(monkeypatch):
+    """``fused_ffn_bwd._dw_block_h`` halves dW's hidden tile when the
+    double-buffered working set would overflow scoped VMEM: at fastmoe-gpt
+    width (d_model 1024) f32 weights need bh=256, bf16 keep bh=512.  The
+    shrunk tile gives the same gradients (a zero budget forces the shrink
+    at test size: bh 512 -> 128, four hidden tiles)."""
+    from repro.kernels import fused_ffn_bwd as fb
+
+    assert fb._dw_block_h(128, 1024, 1024, 512, 1, 4, 4) == 256
+    assert fb._dw_block_h(128, 1024, 1024, 512, 1, 2, 2) == 512
+    monkeypatch.setattr(fb, "_VMEM_BUDGET", 0)
+    assert fb._dw_block_h(8, 16, 24, 512, 1, 4, 4) == 128
+    x, ws, wo, gs = _setup(4, 16, 512, 24, False, seed=5)
+    _check_grads(x, ws, wo, gs, "gelu", bh=512)

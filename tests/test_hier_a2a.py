@@ -8,7 +8,7 @@ inter bounds — while the wire counters split intra/inter and the
 inter-node share shrinks below the flat exchange's bytes.
 
 Host tests exercise the pure plan math (core/dispatch make_hier_agg /
-ragged_recv_compact_hier / hier_chunk_plans), the compat shim, and the
+ragged_recv_compact_hier / hier_chunk_plans), the transport gate, and the
 LoadMonitor's adaptive bound; multi-device cases run in subprocesses via
 tests/dist_utils.py (the main process keeps its single CPU device).
 """
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import dist_utils as du
-from repro import compat
+from repro.core import comm
 from repro.core import dispatch as D
 from repro.core.monitor import LoadMonitor
 
@@ -162,12 +162,13 @@ def test_suggest_ragged_bound_adapts_and_guards():
 
 
 def test_compat_shim_version_gate():
-    """has_ragged_all_to_all reflects the installed jax: true iff
-    lax.ragged_all_to_all exists.  (The fallback-vs-native equality runs in
-    the subprocess test below; on jax without the primitive both calls take
-    the fallback, which the flat-exchange differential already pins.)"""
-    has = compat.has_ragged_all_to_all()
-    assert has == hasattr(jax.lax, "ragged_all_to_all")
+    """The ragged transport is chosen by the devices in use, not by the
+    jax version: XLA:CPU leaves ragged-all-to-all unimplemented, so outside
+    any mesh on this CPU process the dense bounded-shard branch is picked.
+    (tests/test_chip_compile.py compiles the native branch for a described
+    TPU, where the gate flips.)"""
+    assert jax.default_backend() == "cpu"
+    assert comm.native_ragged_all_to_all() is False
 
 
 # ---------------------------------------------------------------------------
@@ -190,9 +191,10 @@ _SETUP = """
 def test_hier_bit_exact_vs_flat_sweep():
     """Acceptance: the two-level exchange is bit-exact vs the flat ragged
     path — outputs AND grads — across impl x overlap x inter_bound on the
-    2-node x 4-inner mesh (8 fake devices).  ib=24 < n_inner*B exercises
-    the slim (but still dropless for this routing) inter leg; oc=4 with
-    pallas/fused exercises per-received-chunk expert compute."""
+    2-node x 4-inner mesh (8 fake devices).  ib=32 < n_inner*B = 128
+    exercises the slim (but still dropless for this routing, asserted)
+    inter leg; oc=4 with pallas/fused exercises per-received-chunk expert
+    compute."""
     out = du.run(_SETUP + """
     def loss(p, x, dist, impl):
         y, _ = fmoe.fmoe_apply(p, x, env.cfg, dist=dist, impl=impl)
@@ -206,8 +208,13 @@ def test_hier_bit_exact_vs_flat_sweep():
             y, g = fn(env.params, env.x)
         return np.asarray(y), g
 
+    with mesh:
+        _, m = jax.jit(lambda p, x: fmoe.fmoe_apply(
+            p, x, env.cfg, dist=hier._replace(inter_bound=32)))(env.params,
+                                                                env.x)
+    assert float(m.obs.dropped) == 0.0, "slim leg must stay dropless here"
     corners = [(impl, oc, ib) for impl in ("einsum", "fused") for oc in (0, 4)
-               for ib in (0, 24)] + [("pallas", 4, 24), ("pallas", 0, 0)]
+               for ib in (0, 32)] + [("pallas", 4, 32), ("pallas", 0, 0)]
     for impl, oc, ib in corners:
         y0, g0 = run(flat._replace(overlap_chunks=oc), impl)
         y1, g1 = run(hier._replace(overlap_chunks=oc, inter_bound=ib), impl)
@@ -216,7 +223,7 @@ def test_hier_bit_exact_vs_flat_sweep():
     # bf16 wire: both levels cast; still bit-exact flat vs hier (identical
     # quantization points), and distinct from the f32-wire output
     yb0, _ = run(flat._replace(wire_dtype="bf16"), "fused")
-    yb1, _ = run(hier._replace(wire_dtype="bf16", inter_bound=24), "fused")
+    yb1, _ = run(hier._replace(wire_dtype="bf16", inter_bound=32), "fused")
     du.assert_bit_exact(yb1, yb0)
     y0, _ = run(flat, "fused")
     assert 0 < float(np.abs(yb0 - y0).max()) < 0.05
@@ -243,6 +250,7 @@ def test_hier_wire_counters_hand_math_hlo_and_shrink():
                         + cb.get("collective-permute", 0))
 
     E, d, mp, n_inner, n_nodes = 8, 32, 8, 4, 2
+    IB = 32  # slim inter bound, below n_inner * B = 128
     B = (128 // 8) * 2  # t_local * k = 32 rows per peer shard
     # flat on the node mesh: everything crosses as inter
     m, hlo = run(flat)
@@ -263,8 +271,8 @@ def test_hier_wire_counters_hand_math_hlo_and_shrink():
 
     # slim inter bound: the inter share (the slow links) shrinks below the
     # flat exchange's bytes; the intra share is untouched
-    m24, hlo24 = run(hier._replace(inter_bound=24))
-    b_inter24 = 4 * (2 * n_nodes * 24 * d + E)
+    m24, hlo24 = run(hier._replace(inter_bound=IB))
+    b_inter24 = 4 * (2 * n_nodes * IB * d + E)
     assert float(m24.obs.wire_bytes_intra) == b_intra
     assert float(m24.obs.wire_bytes_inter) == b_inter24
     assert b_inter24 < b_flat
@@ -272,7 +280,7 @@ def test_hier_wire_counters_hand_math_hlo_and_shrink():
     assert float(m24.drop_frac) == 0.0  # this routing still fits
 
     # decomposed (ppermute) hops: each level keeps its own (s-1)/s fraction
-    md, hlod = run(hier._replace(overlap_chunks=4, inter_bound=24))
+    md, hlod = run(hier._replace(overlap_chunks=4, inter_bound=IB))
     bi = 0.75 * b_intra
     be = 0.5 * b_inter24
     assert float(md.obs.wire_bytes_intra) == bi
@@ -280,9 +288,9 @@ def test_hier_wire_counters_hand_math_hlo_and_shrink():
     assert float(md.obs.wire_bytes) == bi + be == hlod
 
     # bf16 wire: payloads halve on both levels, counts legs stay int32
-    mb, hlob = run(hier._replace(wire_dtype="bf16", inter_bound=24))
+    mb, hlob = run(hier._replace(wire_dtype="bf16", inter_bound=IB))
     assert float(mb.obs.wire_bytes_intra) == 2 * (2 * mp * B * d) + 4 * E
-    assert float(mb.obs.wire_bytes_inter) == 2 * (2 * n_nodes * 24 * d) + 4 * E
+    assert float(mb.obs.wire_bytes_inter) == 2 * (2 * n_nodes * IB * d) + 4 * E
     assert float(mb.obs.wire_bytes) == hlob
     print("hier counters ok")
     """, devices=8)
@@ -332,14 +340,14 @@ def test_hier_skew_drops_and_shadow_compose():
 
 
 def test_compat_shim_branches_agree():
-    """compat.ragged_all_to_all_shards: the dense bounded-shard fallback is
-    bit-identical to the native ragged primitive (when the installed jax
-    has it) and to the plain tiled a2a (always — zero padding is the
-    invariant both transports preserve)."""
+    """comm.ragged_all_to_all_shards on the CPU: the gate picks the dense
+    bounded-shard branch, which is bit-identical to the plain tiled a2a of
+    the zero-padded shards (the invariant the native TPU branch preserves
+    too — tests/test_chip_compile.py compiles that one for a v5e)."""
     out = du.run("""
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from repro.core import comm
     import dist_utils as du
     mesh = du.make_mesh(1, 4)
     mp, bound, d = 4, 6, 8
@@ -351,30 +359,25 @@ def test_compat_shim_branches_agree():
         for p in range(mp):
             send[r, p, :sizes[r, p]] = rng.normal(size=(sizes[r, p], d))
 
-    def make_run(force):
-        def run(s, sz):
-            recv_sz = jax.lax.all_to_all(sz[0].reshape(mp, 1), "model", 0, 0,
-                                         tiled=True).reshape(mp)
-            return compat.ragged_all_to_all_shards(
-                s[0], sz[0], recv_sz, "model", force_fallback=force)[None]
-        return compat.shard_map(run, mesh=mesh,
-                                in_specs=(P("model"), P("model")),
-                                out_specs=P("model"))
-
-    outs = {}
-    for force in ((False, True) if compat.has_ragged_all_to_all()
-                  else (True,)):
-        with mesh:
-            outs[force] = np.asarray(make_run(force)(jnp.asarray(send),
-                                                     jnp.asarray(sizes)))
+    picked = []
+    def run(s, sz):
+        picked.append(comm.native_ragged_all_to_all())
+        recv_sz = jax.lax.all_to_all(sz[0].reshape(mp, 1), "model", 0, 0,
+                                     tiled=True).reshape(mp)
+        return comm.ragged_all_to_all_shards(s[0], sz[0], recv_sz,
+                                             "model")[None]
+    shim = jax.shard_map(run, mesh=mesh, in_specs=(P("model"), P("model")),
+                         out_specs=P("model"), check_vma=False)
     # oracle: the plain tiled a2a of the padded shards
-    plain = compat.shard_map(
+    plain = jax.shard_map(
         lambda s: jax.lax.all_to_all(s[0], "model", 0, 0, tiled=True)[None],
-        mesh=mesh, in_specs=(P("model"),), out_specs=P("model"))
+        mesh=mesh, in_specs=(P("model"),), out_specs=P("model"),
+        check_vma=False)
     with mesh:
+        got = np.asarray(shim(jnp.asarray(send), jnp.asarray(sizes)))
         ref = np.asarray(plain(jnp.asarray(send)))
-    for force, got in outs.items():
-        du.assert_bit_exact(got, ref, msg=force)
+    assert picked == [False], picked  # dense branch on XLA:CPU
+    du.assert_bit_exact(got, ref)
     print("shim branches ok")
     """, devices=4)
     assert "shim branches ok" in out

@@ -79,7 +79,8 @@ def test_ragged_moe_hlo_no_blocking_a2a_no_hidden():
         import jax
         from repro.configs.base import MoEConfig
         from repro.core import fmoe
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(1, 4)
         H = 40
         cfg = MoEConfig(num_experts=8, top_k=2, d_expert_hidden=H,
                         dispatch="ragged")
@@ -137,7 +138,8 @@ def test_pipelined_hlo_collectives_bracket_expert_gemms():
         import jax
         from repro.configs.base import MoEConfig
         from repro.core import fmoe
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(1, 4)
         cfg = MoEConfig(num_experts=8, top_k=2, d_expert_hidden=32,
                         capacity_factor=2.0)
         params = fmoe.fmoe_init(jax.random.PRNGKey(0), 16, cfg)
@@ -240,7 +242,8 @@ def test_pipelined_moe_hlo_has_no_blocking_all_to_all():
         import jax
         from repro.configs.base import MoEConfig
         from repro.core import fmoe
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(1, 4)
         cfg = MoEConfig(num_experts=8, top_k=2, d_expert_hidden=32,
                         capacity_factor=2.0)
         params = fmoe.fmoe_init(jax.random.PRNGKey(0), 16, cfg)

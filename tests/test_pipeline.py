@@ -28,7 +28,8 @@ def test_moe_dist_threads_overlap_options():
     from repro.launch.train import moe_dist
 
     cfg = reduced(get_config("fastmoe-gpt"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh(1, 1)
     dist = moe_dist(cfg, mesh, 64,
                     opts={"overlap_chunks": 4, "wire_dtype": "bf16"})
     assert dist.overlap_chunks == 4 and dist.wire_dtype == "bf16"
@@ -41,7 +42,8 @@ _SETUP = """
     import numpy as np, jax, jax.numpy as jnp
     from repro.configs.base import MoEConfig
     from repro.core import fmoe
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh(2, 4)
     cfg = MoEConfig(num_experts=8, top_k=2, d_expert_hidden=64,
                     capacity_factor=8.0)
     params = fmoe.fmoe_init(jax.random.PRNGKey(0), 32, cfg)
@@ -60,22 +62,21 @@ def test_ppermute_a2a_equals_lax_all_to_all():
     out = du.run("""
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro import compat
     from repro.core.pipeline import chunked_all_to_all, ppermute_all_to_all
+    from repro.launch.mesh import make_local_mesh
 
-    for shape, axes in [((4,), ("model",)), ((2, 2), ("pod", "model"))]:
-        mesh = jax.make_mesh(shape, axes)
-        ax = axes[0] if len(axes) == 1 else axes
+    for mesh, ax in [(make_local_mesh(1, 4), "model"),
+                     (make_local_mesh(1, 2, pod=2), ("pod", "model"))]:
         mp = 4
         x = jnp.arange(4 * 4 * 6 * 5, dtype=jnp.float32).reshape(4 * 4, 6, 5)
         spec = P(ax, None, None)
-        ref = compat.shard_map(
+        ref = jax.shard_map(
             lambda b: jax.lax.all_to_all(b, ax, 0, 0, tiled=True),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
-        pp = compat.shard_map(
+        pp = jax.shard_map(
             lambda b: ppermute_all_to_all(b, ax, mp),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
-        ck = compat.shard_map(
+        ck = jax.shard_map(
             lambda b: chunked_all_to_all(b, ax, mp, 3),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
         with mesh:
@@ -83,10 +84,10 @@ def test_ppermute_a2a_equals_lax_all_to_all():
             np.testing.assert_array_equal(np.asarray(ref(x)), np.asarray(ck(x)))
         # wire cast round-trips through bf16 exactly for bf16 payloads
         xb = x.astype(jnp.bfloat16)
-        ppb = compat.shard_map(
+        ppb = jax.shard_map(
             lambda b: ppermute_all_to_all(b, ax, mp, wire_dtype=jnp.bfloat16),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
-        refb = compat.shard_map(
+        refb = jax.shard_map(
             lambda b: jax.lax.all_to_all(b, ax, 0, 0, tiled=True),
             mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
         with mesh:

@@ -110,7 +110,7 @@ _LM_SETUP = """
     cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
         cfg.moe, num_experts=8, capacity_factor=8.0))
     E, L = 8, cfg.num_layers
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = du.make_mesh(1, 4)
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                               cfg.vocab_size)
@@ -216,7 +216,7 @@ def test_psum_decode_shadowing_bit_exact():
     import dist_utils as du
     from repro.core import fmoe
     from repro.placement import from_logical
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = du.make_mesh(1, 4)
     for dispatch, impl in [("capacity", "einsum"), ("capacity", "fused"),
                            ("ragged", "fused"), ("ragged", "pallas"),
                            ("ragged", "einsum")]:

@@ -139,7 +139,7 @@ def test_ragged_bit_exact_on_1x4_fused():
     import dist_utils as du
     from repro.core import fmoe
     env = du.moe_env(dispatch="ragged", capacity_factor=1.25)
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = du.make_mesh(1, 4)
     dist = fmoe.DistConfig(mesh, ("data", "model"))
     cfg = env.cfg
 

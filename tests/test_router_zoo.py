@@ -47,7 +47,7 @@ def test_router_sweep_bit_exact_1x4(router):
     from repro.core import fmoe
     from repro.placement import from_logical
     router = {router!r}
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = du.make_mesh(1, 4)
     for dispatch in ("capacity", "ragged"):
         env = du.moe_env(dispatch=dispatch, router=router)
         if router == "expert_choice":
@@ -117,7 +117,7 @@ def test_expert_choice_dense_equals_dispatched():
     import dist_utils as du
     from repro.core import fmoe
     from repro.core.gate import expert_choice_moe
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = du.make_mesh(1, 4)
     for dispatch in ("capacity", "ragged"):
         for impl in ("einsum", "fused"):
             env = du.moe_env(dispatch=dispatch, router="expert_choice",

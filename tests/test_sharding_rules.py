@@ -5,9 +5,8 @@ not execution (tests/test_distributed.py covers execution)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
-from repro.compat import make_abstract_mesh
 from repro.configs import get_config, reduced
 from repro.core.sync import fastmoe_tag, grad_sync_axes, spec_axes
 from repro.launch.sharding import _flat_paths, spec_for, tree_specs
@@ -15,7 +14,7 @@ from repro.models import lm
 
 
 def _mesh(shape=(16, 16), axes=("data", "model")):
-    return make_abstract_mesh(shape, axes)
+    return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 @pytest.fixture(scope="module")

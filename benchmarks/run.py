@@ -43,12 +43,9 @@ def main() -> None:
 
     os.makedirs(args.out, exist_ok=True)
     # unified telemetry (repro.obs): every fig's rows also land in
-    # <out>/metrics.jsonl via benchmarks.common, and per-fig wall times in a
-    # Chrome trace next to them
+    # <out>/metrics.jsonl via benchmarks.common
     from benchmarks import common
-    from repro.obs import trace as obs_trace
     common.set_results_dir(args.out)
-    obs_trace.configure(enabled=True)
     # merge into existing results so `--only fig9` doesn't drop fig8's rows
     # (results.json also feeds repro.placement.calibrate)
     results = {}
@@ -62,16 +59,14 @@ def main() -> None:
     print("name,us_per_call,derived")
     for name in picked:
         t0 = time.time()
-        with obs_trace.span(f"bench:{name}"):
-            results[name] = suites[name](quick=args.quick)
+        results[name] = suites[name](quick=args.quick)
         print(f"# {name} done in {time.time() - t0:.1f}s", flush=True)
     results["wire_summary"] = _wire_summary(results)
     with open(os.path.join(args.out, "results.json"), "w") as f:
         json.dump(results, f, indent=1)
     common.set_results_dir(None)  # flush + close metrics.jsonl
-    obs_trace.export(os.path.join(args.out, "trace.json"))
     print(f"# wrote {args.out}/results.json")
-    print(f"# wrote {args.out}/metrics.jsonl and {args.out}/trace.json")
+    print(f"# wrote {args.out}/metrics.jsonl")
 
 
 def _wire_summary(results: dict) -> dict:

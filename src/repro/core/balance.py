@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs.counters import ObsCounters
+from repro.obs.scopes import ROUTER, scoped
 
 
 class MoEMetrics(NamedTuple):
@@ -48,6 +49,7 @@ def load_balance_loss(probs: jax.Array, expert_ids: jax.Array,
     return num_experts * jnp.sum(f * p)
 
 
+@scoped(ROUTER)
 def router_z_loss(logits: jax.Array) -> jax.Array:
     """ST-MoE z-loss: mean(logsumexp(logits)^2) — keeps router logits small."""
     return jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)

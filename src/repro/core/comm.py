@@ -5,7 +5,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import EXCHANGE, scoped
 
+
+@scoped(EXCHANGE)
 def exchange_counts(counts: jax.Array, axis: str) -> jax.Array:
     """Fig 2 step 1: exchange per-expert token counts over the expert axis.
 
@@ -16,6 +19,7 @@ def exchange_counts(counts: jax.Array, axis: str) -> jax.Array:
     return jax.lax.all_to_all(counts.reshape(mp, -1), axis, 0, 0, tiled=True)
 
 
+@scoped(EXCHANGE)
 def exchange_tokens(buf: jax.Array, axis: str) -> jax.Array:
     """Fig 2 step 2: payload all-to-all.  buf (E, C, d) -> (E_local, mp*C, d)."""
     mp = jax.lax.axis_size(axis)
@@ -25,6 +29,7 @@ def exchange_tokens(buf: jax.Array, axis: str) -> jax.Array:
     return buf.transpose(1, 0, 2, 3).reshape(E // mp, mp * C, d)
 
 
+@scoped(EXCHANGE)
 def return_tokens(out: jax.Array, axis: str) -> jax.Array:
     """Inverse of :func:`exchange_tokens`: (E_local, mp*C, d) -> (E, C, d)."""
     mp = jax.lax.axis_size(axis)
@@ -48,6 +53,7 @@ def native_ragged_all_to_all() -> bool:
     return kind.lower() != "cpu"
 
 
+@scoped(EXCHANGE)
 def ragged_all_to_all_shards(send, send_sizes, recv_sizes, axis):
     """Exchange ``(mp, bound, ...)`` per-peer shards, valid-prefix ragged.
 
@@ -76,6 +82,7 @@ def ragged_all_to_all_shards(send, send_sizes, recv_sizes, axis):
     return out.reshape(send.shape)
 
 
+@scoped(EXCHANGE)
 def exchange_ragged(send: jax.Array, counts: jax.Array, axis, mp: int, *,
                     n_chunks: int = 1, wire_dtype=None, fill_fn=None):
     """Ragged (dropless) global data exchange, forward direction.
@@ -99,6 +106,7 @@ def exchange_ragged(send: jax.Array, counts: jax.Array, axis, mp: int, *,
     return recv, incoming, fill_out
 
 
+@scoped(EXCHANGE)
 def return_ragged(out: jax.Array, axis, mp: int, *, n_chunks: int = 1,
                   wire_dtype=None) -> jax.Array:
     """Inverse of :func:`exchange_ragged`'s payload leg: (mp, bound, d_out)
@@ -111,6 +119,7 @@ def return_ragged(out: jax.Array, axis, mp: int, *, n_chunks: int = 1,
                                        decompose=n_chunks > 1)
 
 
+@scoped(EXCHANGE)
 def exchange_ragged_intra(send: jax.Array, counts: jax.Array, inner_axis,
                           n_inner: int, *, decompose: bool = False,
                           wire_dtype=None):
@@ -135,6 +144,7 @@ def exchange_ragged_intra(send: jax.Array, counts: jax.Array, inner_axis,
     return shards, cnt
 
 
+@scoped(EXCHANGE)
 def return_ragged_intra(out: jax.Array, inner_axis, n_inner: int, *,
                         decompose: bool = False, wire_dtype=None) -> jax.Array:
     """Inverse of :func:`exchange_ragged_intra`'s payload hop: de-aggregated
@@ -146,6 +156,7 @@ def return_ragged_intra(out: jax.Array, inner_axis, n_inner: int, *,
                                     decompose=decompose, wire_dtype=wire_dtype)
 
 
+@scoped(EXCHANGE)
 def exchange_ragged_inter(slim: jax.Array, kept_counts: jax.Array, node_axis,
                           n_nodes: int, *, n_chunks: int = 1, wire_dtype=None,
                           fill_fn=None):
@@ -180,6 +191,7 @@ def exchange_ragged_inter(slim: jax.Array, kept_counts: jax.Array, node_axis,
     return recv, incoming, fill_out
 
 
+@scoped(EXCHANGE)
 def return_ragged_inter(out: jax.Array, kept_counts: jax.Array,
                         incoming: jax.Array, node_axis, n_nodes: int, *,
                         n_chunks: int = 1, wire_dtype=None) -> jax.Array:
@@ -199,6 +211,7 @@ def return_ragged_inter(out: jax.Array, kept_counts: jax.Array,
                                        decompose=n_chunks > 1)
 
 
+@scoped(EXCHANGE)
 def hierarchical_all_to_all(buf: jax.Array, inner_axis: str,
                             outer_axis: str) -> jax.Array:
     """Beyond-paper: 2-hop all-to-all for multi-pod meshes.
@@ -218,6 +231,7 @@ def hierarchical_all_to_all(buf: jax.Array, inner_axis: str,
     return buf
 
 
+@scoped(EXCHANGE)
 def all_to_all_bf16(buf: jax.Array, axis: str, split_axis: int = 0,
                     concat_axis: int = 0) -> jax.Array:
     """Beyond-paper: cast payload to bf16 across the wire (halves collective

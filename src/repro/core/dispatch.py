@@ -21,6 +21,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import COMBINE, DISPATCH, scoped
+
 
 def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
                     capacity_factor: float, *, multiple: int = 8) -> int:
@@ -44,6 +46,7 @@ class CapacityPlan(NamedTuple):
     capacity: int
 
 
+@scoped(DISPATCH)
 def make_capacity_plan(expert_ids: jax.Array, num_experts: int,
                        capacity) -> CapacityPlan:
     """Assign buffer positions with slot-major priority (top-1 choices first),
@@ -76,6 +79,7 @@ def make_capacity_plan(expert_ids: jax.Array, num_experts: int,
     return CapacityPlan(expert_ids, unflatten(pos), unflatten(keep), load, width)
 
 
+@scoped(DISPATCH)
 def dispatch_capacity(x: jax.Array, plan: CapacityPlan,
                       num_experts: int) -> jax.Array:
     """Scatter tokens (T, d) into per-expert buffers (E, C, d)."""
@@ -89,6 +93,7 @@ def dispatch_capacity(x: jax.Array, plan: CapacityPlan,
     return buf.at[eid, pos].set(x[rows], mode="drop")
 
 
+@scoped(COMBINE)
 def combine_capacity(out_buf: jax.Array, plan: CapacityPlan,
                      combine_weights: jax.Array) -> jax.Array:
     """Gather expert outputs (E, C, dout) back to token order, weighted-sum over k."""
@@ -101,6 +106,7 @@ def combine_capacity(out_buf: jax.Array, plan: CapacityPlan,
     return jnp.einsum("tk,tkd->td", w, gathered)
 
 
+@scoped(COMBINE)
 def combine_capacity_slots(out_buf: jax.Array, plan: CapacityPlan,
                            combine_weights: jax.Array) -> jax.Array:
     """Per-slot weighted expert outputs (T, k, dout) — no k-reduction.
@@ -133,6 +139,7 @@ class RaggedPlan(NamedTuple):
     token_rows: jax.Array  # (T*k,) int32 — source token per sorted row
 
 
+@scoped(DISPATCH)
 def make_ragged_plan(expert_ids: jax.Array, num_experts: int) -> RaggedPlan:
     T, k = expert_ids.shape
     flat = expert_ids.reshape(-1)  # token-major
@@ -142,11 +149,13 @@ def make_ragged_plan(expert_ids: jax.Array, num_experts: int) -> RaggedPlan:
     return RaggedPlan(sort_idx, group_sizes, token_rows)
 
 
+@scoped(DISPATCH)
 def dispatch_ragged(x: jax.Array, plan: RaggedPlan) -> jax.Array:
     """Gather tokens (T, d) into expert-sorted order (T*k, d)."""
     return x[plan.token_rows]
 
 
+@scoped(COMBINE)
 def combine_ragged(y_sorted: jax.Array, plan: RaggedPlan,
                    combine_weights: jax.Array) -> jax.Array:
     """Un-sort expert outputs (T*k, dout) and weighted-sum the k slots."""
@@ -156,6 +165,7 @@ def combine_ragged(y_sorted: jax.Array, plan: RaggedPlan,
     return jnp.einsum("tk,tkd->td", combine_weights.astype(y.dtype), y)
 
 
+@scoped(COMBINE)
 def combine_ragged_slots(y_sorted: jax.Array, plan: RaggedPlan,
                          combine_weights: jax.Array) -> jax.Array:
     """Ragged analogue of :func:`combine_capacity_slots`: un-sorted per-slot
@@ -193,6 +203,7 @@ def ec_capacity(num_tokens: int, num_experts: int,
                       int(num_tokens * capacity_factor / num_experts)))
 
 
+@scoped(COMBINE)
 def combine_ec(out: jax.Array, token_idx: jax.Array, weights: jax.Array,
                num_tokens: int) -> jax.Array:
     """Scatter-add weighted expert outputs back to token order.
@@ -208,6 +219,7 @@ def combine_ec(out: jax.Array, token_idx: jax.Array, weights: jax.Array,
         (out * weights[..., None].astype(out.dtype)).reshape(E * C, dout))
 
 
+@scoped(DISPATCH)
 def ec_to_physical(token_idx: jax.Array, table: jax.Array | None) -> jax.Array:
     """Permute the (E, C) expert-choice token grid from logical to physical
     expert order (row e of the result belongs to physical slot e).  Uniform
@@ -249,6 +261,7 @@ class RaggedXPlan(NamedTuple):
     num_owned_rows: jax.Array  # () int32 — rows routed to owned experts
 
 
+@scoped(DISPATCH)
 def make_ragged_xplan(group_sizes: jax.Array, num_rows: int, num_owned: int,
                       num_peers: int, bound: int) -> RaggedXPlan:
     """Lay this rank's ``num_rows`` sorted rows into per-peer shards of width
@@ -280,6 +293,7 @@ def make_ragged_xplan(group_sizes: jax.Array, num_rows: int, num_owned: int,
     return RaggedXPlan(send_dest, peer_counts, keep, num_owned_rows)
 
 
+@scoped(DISPATCH)
 def ragged_recv_compact(incoming: jax.Array, bound: int):
     """Compaction map for the received (mp, bound, d) shards.
 
@@ -337,6 +351,7 @@ class HierAggPlan(NamedTuple):
     dropped: jax.Array  # () f32 — rows this agent dropped at the inter bound
 
 
+@scoped(DISPATCH)
 def make_hier_agg(cnt_agg: jax.Array, bound: int,
                   inter_bound: int) -> HierAggPlan:
     """Compact per-sibling padded shards into slim per-node shards.
@@ -393,6 +408,7 @@ def _hier_slots(incoming: jax.Array, inter_bound: int):
     return i, s, r, e, valid
 
 
+@scoped(DISPATCH)
 def ragged_recv_compact_hier(incoming: jax.Array, inter_bound: int):
     """Two-level analogue of :func:`ragged_recv_compact`.
 
@@ -414,6 +430,7 @@ def ragged_recv_compact_hier(incoming: jax.Array, inter_bound: int):
             gs.astype(jnp.int32))
 
 
+@scoped(DISPATCH)
 def hier_chunk_plans(incoming: jax.Array, inter_bound: int, n_chunks: int):
     """Per-chunk mini-compaction maps for per-received-chunk expert compute.
 

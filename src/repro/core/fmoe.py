@@ -30,6 +30,7 @@ from repro.core.gate import (expert_choice_forward, gate_forward, gate_init,
                              route_tokens, router_distill_loss, router_init)
 from repro.obs import counters as obs_counters
 from repro.obs.counters import ObsCounters
+from repro.obs.scopes import EXPERTS, ROUTER, scoped
 
 
 class DistConfig(NamedTuple):
@@ -180,6 +181,7 @@ def dense_ffn(params: dict, x: jax.Array, act: str) -> jax.Array:
     return h @ params["wo"]
 
 
+@scoped(EXPERTS)
 def expert_ffn(params: dict, xs: jax.Array, act: str) -> jax.Array:
     """Default ``expert_fn``: batched per-expert FFN on (E, n, d) buffers.
 
@@ -200,6 +202,7 @@ def _expert_ws(params: dict, act: str) -> tuple:
             else (params["wi"],))
 
 
+@scoped(EXPERTS)
 def expert_ffn_pallas(params: dict, xs: jax.Array, act: str) -> jax.Array:
     """expert_fn backed by the Pallas grouped-GEMM kernel (equal-size groups)."""
     from repro.kernels import grouped_gemm as gg
@@ -221,6 +224,7 @@ def expert_ffn_pallas(params: dict, xs: jax.Array, act: str) -> jax.Array:
                               "pallas", gg.DEFAULT_BM, aligned).reshape(E, n, -1)
 
 
+@scoped(EXPERTS)
 def expert_ffn_fused(params: dict, xs: jax.Array, act: str) -> jax.Array:
     """expert_fn backed by the fused GEMM1+act+GEMM2 Pallas kernel.
 
@@ -253,6 +257,7 @@ EXPERT_FNS: dict[str, Callable] = {
 # EXPERT_FNS so every dispatch mode exposes every impl.
 
 
+@scoped(EXPERTS)
 def ragged_ffn_two_pass(params: dict, xs: jax.Array, group_sizes: jax.Array,
                         act: str, impl: str = "pallas") -> jax.Array:
     from repro.kernels import ops
@@ -261,6 +266,7 @@ def ragged_ffn_two_pass(params: dict, xs: jax.Array, group_sizes: jax.Array,
                             group_sizes, act, impl)
 
 
+@scoped(EXPERTS)
 def ragged_ffn_fused(params: dict, xs: jax.Array, group_sizes: jax.Array,
                      act: str) -> jax.Array:
     from repro.kernels import ops
@@ -336,6 +342,7 @@ def _imbalance(owned_load: jax.Array, mp: int, E_local: int) -> jax.Array:
     return per_rank.max() / jnp.maximum(per_rank.mean(), 1e-6)
 
 
+@scoped(ROUTER)
 def _aux_loss(router: dict, x: jax.Array, g, cfg: MoEConfig) -> jax.Array:
     """Balance loss, plus the StableMoE stage-1 distillation term whenever a
     frozen-router-to-be is riding along (its gradient reaches only
@@ -346,6 +353,7 @@ def _aux_loss(router: dict, x: jax.Array, g, cfg: MoEConfig) -> jax.Array:
     return aux
 
 
+@scoped(ROUTER)
 def _ec_route(router: dict, x: jax.Array, cfg: MoEConfig, table):
     """Expert-choice routing shared by the four MoE paths.
 

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
+from repro.obs.scopes import ROUTER, scoped
 
 
 class GateOutput(NamedTuple):
@@ -136,6 +137,7 @@ def frozen_forward(params: dict, x: jax.Array, cfg: MoEConfig) -> GateOutput:
     return GateOutput(expert_ids.astype(jnp.int32), weights, probs, logits)
 
 
+@scoped(ROUTER)
 def route_tokens(params: dict, x: jax.Array, cfg: MoEConfig, *,
                  rng: jax.Array | None = None) -> GateOutput:
     """Dispatch to the token-choice router selected by ``cfg.router``.

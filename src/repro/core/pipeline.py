@@ -33,6 +33,8 @@ from typing import Callable, Optional
 import jax.numpy as jnp
 from jax import lax
 
+from repro.obs.scopes import EXCHANGE, scoped
+
 
 _BITS_FOR_BYTES = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
 
@@ -59,6 +61,7 @@ def _from_wire(x: jnp.ndarray, orig, wire_dtype):
     return lax.bitcast_convert_type(x, wire_dtype).astype(orig)
 
 
+@scoped(EXCHANGE)
 def ppermute_all_to_all(x: jnp.ndarray, axis, mp: int, *,
                         wire_dtype=None) -> jnp.ndarray:
     """``lax.all_to_all(x, axis, 0, 0, tiled=True)`` as mp-1 collective-permutes.
@@ -91,6 +94,7 @@ def ppermute_all_to_all(x: jnp.ndarray, axis, mp: int, *,
     return _from_wire(out, orig, wd)
 
 
+@scoped(EXCHANGE)
 def chunked_all_to_all(x: jnp.ndarray, axis, mp: int, n_chunks: int, *,
                        wire_dtype=None, decompose: bool = True) -> jnp.ndarray:
     """Tiled dim-0 all-to-all split into ``n_chunks`` independent exchanges.
@@ -108,6 +112,7 @@ def chunked_all_to_all(x: jnp.ndarray, axis, mp: int, n_chunks: int, *,
                            axis=1)
 
 
+@scoped(EXCHANGE)
 def _plain_all_to_all(x, *, axis, mp, wire_dtype=None):
     del mp
     orig = x.dtype
@@ -115,6 +120,7 @@ def _plain_all_to_all(x, *, axis, mp, wire_dtype=None):
     return _from_wire(lax.all_to_all(x, axis, 0, 0, tiled=True), orig, wd)
 
 
+@scoped(EXCHANGE)
 def counts_all_to_all(counts: jnp.ndarray, axis, mp: int, *,
                       decompose: bool) -> jnp.ndarray:
     """The Fig-2 "exchange sizes" step: (mp, E_local) per-destination counts
@@ -154,6 +160,7 @@ def resolve_chunks(requested: int, capacity: int) -> int:
     return n
 
 
+@scoped(EXCHANGE)
 def ragged_pipelined_exchange(send: jnp.ndarray, axis, mp: int, n_chunks: int,
                               *, fill_fn: Optional[Callable[[], jnp.ndarray]] = None,
                               wire_dtype=None):
@@ -184,6 +191,7 @@ def ragged_pipelined_exchange(send: jnp.ndarray, axis, mp: int, n_chunks: int,
     return jnp.concatenate(recvs, axis=1), fill_out
 
 
+@scoped(EXCHANGE)
 def all_to_all_dim1(x: jnp.ndarray, axis, mp: int, *, decompose: bool = False,
                     wire_dtype=None) -> jnp.ndarray:
     """Tiled all-to-all splitting/concatenating on dim 1 (dim1 size == mp).
@@ -200,6 +208,7 @@ def all_to_all_dim1(x: jnp.ndarray, axis, mp: int, *, decompose: bool = False,
               wire_dtype=wire_dtype).transpose(perm)
 
 
+@scoped(EXCHANGE)
 def hier_ragged_pipeline(send: jnp.ndarray, axis, mp: int, n_chunks: int,
                          chunk_fn: Callable[[jnp.ndarray, int], jnp.ndarray],
                          *, fill_fn: Optional[Callable[[], jnp.ndarray]] = None,
@@ -240,6 +249,7 @@ def hier_ragged_pipeline(send: jnp.ndarray, axis, mp: int, n_chunks: int,
     return jnp.concatenate(outs, axis=1), fill_out
 
 
+@scoped(EXCHANGE)
 def pipelined_expert_exchange(
         buf: jnp.ndarray, axis, mp: int, n_chunks: int,
         compute_fn: Callable[[jnp.ndarray], jnp.ndarray], *,

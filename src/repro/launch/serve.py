@@ -362,8 +362,10 @@ def main() -> None:
                          "tokens/sec, device-side wire/drop/shadow counters "
                          "(repro.obs; needs --mesh)")
     ap.add_argument("--trace", default="",
-                    help="write a Chrome trace of host-side decode_step "
-                         "spans (chrome://tracing / perfetto)")
+                    help="record a profiler trace into this directory "
+                         "(.xplane.pb and a Perfetto perfetto_trace.json.gz): "
+                         "with --mesh, decode positions 1 .. 5 and their "
+                         "decode_step spans; otherwise the whole run")
     ap.add_argument("--router", default="",
                     choices=["", "topk", "noisy_topk", "gumbel",
                              "expert_choice", "frozen"],
@@ -393,15 +395,23 @@ def main() -> None:
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     prompt = jax.random.randint(jax.random.PRNGKey(1),
                                 (args.batch, args.prompt_len), 0, cfg.vocab_size)
+    window = None
+    if scfg.trace:
+        # the mesh decode loop records positions 1.. (position 0 compiles);
+        # the other paths have no step loop here and record the whole run
+        stepped = args.mesh and not args.continuous
+        window = obs_trace.Window(scfg.trace, 1 if stepped else 0)
     if args.continuous:
         n_req = args.requests or 3 * scfg.slots
+        if window is not None:
+            window.at(0)
         serve_continuous(params, cfg, scfg, prompt_len=args.prompt_len,
                          gen=args.gen, num_requests=n_req, sink=sink)
         if sink is not None:
             sink.close()
             print(f"metrics written to {scfg.metrics_out}")
-        if scfg.trace:
-            obs_trace.export(scfg.trace)
+        if window is not None:
+            window.close()
             print(f"trace written to {scfg.trace}")
         return
     if args.mesh:
@@ -425,6 +435,8 @@ def main() -> None:
         t0 = time.time()
         with mesh:
             for pos in range(seq_len - 1):
+                if window is not None:
+                    window.at(pos)
                 ts = time.time()
                 with obs_trace.span("decode_step", pos=pos):
                     logits, cache, md = step(params, tok, jnp.int32(pos), cache)
@@ -449,6 +461,8 @@ def main() -> None:
             print(f"decode: {len(lat)} steps, p50 {p50 * 1e3:.1f}ms "
                   f"p99 {p99 * 1e3:.1f}ms")
     else:
+        if window is not None:
+            window.at(0)
         t0 = time.time()
         seq = generate(params, cfg, prompt, args.gen)
     dt = time.time() - t0
@@ -458,8 +472,8 @@ def main() -> None:
     if sink is not None:
         sink.close()
         print(f"metrics written to {args.metrics_out}")
-    if args.trace:
-        obs_trace.export(args.trace)
+    if window is not None:
+        window.close()
         print(f"trace written to {args.trace}")
 
 

@@ -525,8 +525,11 @@ def main() -> None:
                          "timeline — faults, guard skips/restores, "
                          "checkpoint saves, resumes, rollbacks (repro.obs)")
     ap.add_argument("--trace", default="",
-                    help="write a Chrome trace (chrome://tracing / perfetto) "
-                         "of host-side spans: train_step, replan, migrate")
+                    help="record a profiler trace into this directory of "
+                         "steps start+1 .. start+5 (the first step compiles): "
+                         "device ops by their fmoe.* scopes and the host "
+                         "spans train_step, replan, migrate, as .xplane.pb "
+                         "and a Perfetto perfetto_trace.json.gz")
     args = ap.parse_args()
 
     from repro.launch.compile_cache import enable_compile_cache
@@ -642,6 +645,9 @@ def main() -> None:
                           snapshot_every=args.snapshot_every, sink=sink)
 
     telemetry = sink is not None or obs_trace.enabled()
+    # the first step compiles: the profiler window starts after it
+    window = (obs_trace.Window(args.trace, start_step + 1) if args.trace
+              else None)
     modeled: dict = {}
     data = SyntheticLM(cfg.vocab_size, args.seq)
     batch_iter = data.batches(args.batch)
@@ -653,6 +659,8 @@ def main() -> None:
         guard.commit(start_step - 1, params, opt_state)
     try:
         while step < args.steps:
+            if window is not None:
+                window.at(step)
             batch = {k: jnp.asarray(v) for k, v in next(batch_iter).items()}
             if (args.freeze_router_at and step >= args.freeze_router_at
                     and cfg.moe is not None and cfg.moe.router != "frozen"):
@@ -789,6 +797,9 @@ def main() -> None:
         if sink is not None:
             sink.close()
         raise SystemExit(1)
+    finally:
+        if window is not None:
+            window.close()
     if manager is not None and step > start_step:
         # final save so a completed run is always resumable/extendable
         manager.maybe_save(step - 1, {"params": params, "opt": opt_state},
@@ -799,7 +810,6 @@ def main() -> None:
         sink.close()
         print(f"metrics written to {args.metrics_out}")
     if args.trace:
-        obs_trace.export(args.trace)
         print(f"trace written to {args.trace}")
 
 

@@ -21,6 +21,7 @@ from repro.models import attention as A
 from repro.models import mamba as M
 from repro.models import rwkv6 as R
 from repro.models.layers import apply_norm, norm_init
+from repro.obs.scopes import ATTENTION, scoped
 
 FULL_WINDOW = 1 << 30  # "no window" sentinel (larger than any seq len)
 
@@ -92,6 +93,7 @@ def _apply_ffn(p: dict, cfg: ModelConfig, x: jax.Array,
     return dense_ffn(p, x, cfg.act), None
 
 
+@scoped(ATTENTION)
 def _mixer_seq(p: dict, cfg: ModelConfig, x: jax.Array, window,
                state: Optional[Any]):
     """Token mixer over a full sequence.  Returns (y, new_state)."""
@@ -109,6 +111,7 @@ def _mixer_seq(p: dict, cfg: ModelConfig, x: jax.Array, window,
     return A.gqa_apply(p["attn"], x, a, window=window), None
 
 
+@scoped(ATTENTION)
 def _mixer_decode(p: dict, cfg: ModelConfig, x: jax.Array, cache, pos, window,
                   block_tables=None):
     if cfg.family == "ssm":

@@ -28,6 +28,7 @@ from repro.models import attention as A
 from repro.models import blocks as B
 from repro.models.layers import (apply_norm, embed_init, embed_lookup,
                                  linear, linear_init, norm_init, unembed)
+from repro.obs.scopes import HEAD_LOSS, scoped
 
 
 def _n_experts(cfg: ModelConfig) -> int:
@@ -91,6 +92,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> dict:
     return p
 
 
+@scoped(HEAD_LOSS)
 def _logits(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.tie_embeddings:
         return unembed(params["embed"], x)
@@ -190,6 +192,14 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     return logits, metrics
 
 
+@scoped(HEAD_LOSS)
+def _next_token_ce(logits: jax.Array, tokens: jax.Array) -> jax.Array:
+    """Mean next-token cross-entropy, in float32."""
+    logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
+    return nll.mean()
+
+
 def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *,
             dist: Optional[DistConfig] = None, impl: str = "einsum",
             rng: Optional[jax.Array] = None):
@@ -204,10 +214,7 @@ def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *,
                                      impl=impl, layer_loads=True, rng=rng)
     if cfg.frontend == "vision" and "patches" in batch:
         logits = logits[:, batch["patches"].shape[1]:]  # text positions only
-    targets = tokens[:, 1:]
-    logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    ce = nll.mean()
+    ce = _next_token_ce(logits, tokens)
     loss = ce
     if cfg.moe is not None:
         L = cfg.num_layers

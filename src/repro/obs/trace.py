@@ -1,111 +1,77 @@
-"""Host-side span tracer: low-overhead wall-time spans with Chrome-trace
-JSON export.
+"""Host spans on the profiler's clock, and the CLIs' profiler window.
 
-Disabled by default — ``span()`` is then a no-op context manager costing one
-attribute read, so instrumented code paths (train step, replan/migrate,
-checkpoint, serve decode) can leave their spans in unconditionally.  Enable
-with :func:`configure`; export with :func:`export` (view in
-``chrome://tracing`` / https://ui.perfetto.dev).
+``span(name, **args)`` marks host work: the train CLI's ``train_step``,
+``replan`` and ``migrate``, ``ckpt_save``/``ckpt_restore``, the serve CLI's
+``decode_step``.  Off by default, it is then a no-op costing one global
+read, so the instrumented paths keep their spans unconditionally.  Once
+:func:`configure` turns it on, a span enters a
+``jax.profiler.TraceAnnotation`` of the same name: a profiler trace then
+holds it on the host plane, on one clock with the device ops and the
+program's named scopes (``repro.obs.scopes``).
 
-Events are complete-span ("ph": "X") Chrome trace events in microseconds
-relative to tracer start, ring-buffered so long runs can't leak host memory.
-Nesting is implicit (Chrome derives it from ts/dur on one tid), but the
-tracer also records the span ``depth`` for programmatic consumers.
+:class:`Window` records such a trace over a few steps after warm-up
+(``--trace DIR`` of ``launch/train.py`` and ``launch/serve.py``): the
+``.xplane.pb`` under ``DIR/plugins/profile/<run>/`` and a Perfetto
+``perfetto_trace.json.gz`` beside it (https://ui.perfetto.dev opens it).
+
+JAX is imported only when a span or window is used, so ``repro.core`` can
+import this package without cycles.
 """
 from __future__ import annotations
 
-import json
-import threading
-import time
-from collections import deque
 from contextlib import contextmanager
-from typing import Optional
+
+WINDOW_STEPS = 5  # steps a --trace window records
+
+_ENABLED = False
 
 
-class Tracer:
-    def __init__(self, *, enabled: bool = True, max_events: int = 100_000,
-                 clock=time.perf_counter):
-        self.enabled = enabled
-        self.max_events = max_events
-        self._clock = clock
-        self._t0 = clock()
-        self._events: deque = deque(maxlen=max_events)
-        self._local = threading.local()
-
-    @contextmanager
-    def span(self, name: str, **args):
-        """Time a region.  Yields the (mutable) args dict when enabled so the
-        body can attach results (``s["tokens"] = n``), or None when disabled.
-        """
-        if not self.enabled:
-            yield None
-            return
-        depth = getattr(self._local, "depth", 0)
-        self._local.depth = depth + 1
-        t0 = self._clock()
-        try:
-            yield args
-        finally:
-            t1 = self._clock()
-            self._local.depth = depth
-            ev = {"name": name, "ph": "X",
-                  "ts": (t0 - self._t0) * 1e6,
-                  "dur": (t1 - t0) * 1e6,
-                  "pid": 0, "tid": threading.get_ident(),
-                  "args": {"depth": depth, **args}}
-            self._events.append(ev)
-
-    @property
-    def events(self) -> list:
-        return list(self._events)
-
-    def chrome_trace(self) -> dict:
-        return {"traceEvents": list(self._events), "displayTimeUnit": "ms"}
-
-    def export(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-        return path
-
-    def reset(self) -> None:
-        self._events.clear()
-        self._t0 = self._clock()
-
-
-# Module-level singleton: the instrumented code paths (train/serve/ckpt/
-# benchmarks) all talk to this, so one --trace flag lights them all up.
-_TRACER = Tracer(enabled=False)
-
-
-def configure(*, enabled: bool = True,
-              max_events: int = 100_000) -> Tracer:
-    """(Re)configure the global tracer; returns it."""
-    global _TRACER
-    _TRACER = Tracer(enabled=enabled, max_events=max_events)
-    return _TRACER
-
-
-def get() -> Tracer:
-    return _TRACER
+def configure(*, enabled: bool = True) -> None:
+    """Turn the spans on (or off) for this process."""
+    global _ENABLED
+    _ENABLED = enabled
 
 
 def enabled() -> bool:
-    return _TRACER.enabled
+    return _ENABLED
 
 
+@contextmanager
 def span(name: str, **args):
-    return _TRACER.span(name, **args)
+    """A ``TraceAnnotation`` named ``name`` carrying ``args`` while spans are
+    on; nothing otherwise."""
+    if not _ENABLED:
+        yield
+        return
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation(name, **args):
+        yield
 
 
-def export(path: str) -> str:
-    return _TRACER.export(path)
+class Window:
+    """A profiler trace of steps ``[first, first + steps)`` into ``log_dir``.
 
+    Call :meth:`at` with each step's index before running it, and
+    :meth:`close` once the loop ends (it stops a window the run cut short).
+    """
 
-def reset() -> None:
-    _TRACER.reset()
+    def __init__(self, log_dir: str, first: int, steps: int = WINDOW_STEPS):
+        self.log_dir, self.first, self.last = log_dir, first, first + steps
+        self.on = False
 
+    def at(self, step: int) -> None:
+        import jax
 
-def load_trace(path: str) -> dict:
-    """Read back an exported Chrome trace (tests / tooling)."""
-    with open(path) as f:
-        return json.load(f)
+        if step == self.first and not self.on:
+            jax.profiler.start_trace(self.log_dir, create_perfetto_trace=True)
+            self.on = True
+        elif step == self.last:
+            self.close()
+
+    def close(self) -> None:
+        import jax
+
+        if self.on:
+            jax.profiler.stop_trace()
+            self.on = False

@@ -7,6 +7,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import OPTIMIZER, scoped
+
 
 class AdamWState(NamedTuple):
     step: jax.Array
@@ -30,6 +32,7 @@ class AdamW(NamedTuple):
                           jax.tree.map(zeros, params),
                           jax.tree.map(zeros, params))
 
+    @scoped(OPTIMIZER)
     def update(self, grads, state: AdamWState, params, *,
                lr_scale: jax.Array | float = 1.0):
         """Returns (new_params, new_state, grad_norm)."""

@@ -139,3 +139,61 @@ def test_native_ragged_all_to_all_compiles_for_v5e(topo):
     txt = jax.jit(fn).lower(*args).compile().as_text()
     assert picked == [True]
     assert "ragged-all-to-all" in txt
+
+
+def _scope_counts(hlo: str) -> dict:
+    """{scope: instructions of the optimized HLO whose op_name holds it}."""
+    import re
+
+    from repro.obs import scopes
+
+    counts = dict.fromkeys(scopes.ALL, 0)
+    for op in re.findall(r'op_name="([^"]*)"', hlo):
+        for name in set(re.findall(r"fmoe\.[a-z_]+", op)) & set(counts):
+            counts[name] += 1
+    return counts
+
+
+def test_train_step_scopes_name_instructions_for_v5e(topo, one_chip):
+    """Every stage scope survives the TPU compiler: in the optimized HLO of
+    a tiny one-chip step (the _moe_local path) and of a tiny 1x4 step (the
+    _moe_a2a path, which adds fmoe.exchange) for described v5e chips, each
+    of the eight names at least one instruction."""
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs import get_config
+    from repro.configs.base import reduced
+    from repro.launch.train import jit_train_step, make_train_step, moe_dist
+    from repro.models import lm
+    from repro.obs import scopes
+    from repro.optim.adamw import AdamW
+
+    opt = AdamW()
+    tokens = jax.ShapeDtypeStruct((8, 64), jnp.int32)
+    step_no = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def state(cfg, sharding=None):
+        p = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0), cfg))
+        o = jax.eval_shape(opt.init, p)
+        if sharding is not None:
+            p, o = jax.tree.map(lambda a: _shape(a.shape, a.dtype, sharding),
+                                (p, o))
+        return p, o
+
+    cfg = reduced(get_config("switch-base-128"), num_layers=1, d_model=128)
+    p, o = state(cfg, one_chip)
+    local = (jax.jit(make_train_step(cfg, opt))
+             .lower(p, o, {"tokens": _shape((8, 64), jnp.int32, one_chip)},
+                    _shape((), jnp.int32, one_chip)).compile().as_text())
+    got = _scope_counts(local)
+    assert all(got[s] for s in scopes.ALL if s != scopes.EXCHANGE), got
+
+    cfg = reduced(get_config("fastmoe-gpt"), num_layers=2, d_model=128)
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    assert moe_dist(cfg, mesh, 8 * 64).mode == "a2a"
+    step, _, _ = jit_train_step(cfg, opt, mesh, 8, 64)
+    p, o = state(cfg)
+    a2a = step.lower(p, o, {"tokens": tokens}, step_no).compile().as_text()
+    got = _scope_counts(a2a)
+    assert all(got.values()), got

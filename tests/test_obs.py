@@ -1,6 +1,6 @@
 """Unified telemetry (repro.obs): device-side wire/drop/shadow counters on
-the MoE metrics pytree, the host-side span tracer, and the pluggable
-metrics sinks.
+the MoE metrics pytree, host spans on the profiler's clock, the train
+step's named scopes, and the pluggable metrics sinks.
 
 The wire counters' contract is strong: for every distributed schedule
 (serial a2a, ppermute-decomposed, bf16 wire, ragged/dropless) the counter
@@ -9,6 +9,7 @@ collective output bytes (roofline.collective_bytes) — and turning the
 counters off (DistConfig.obs=False) must leave the program's collectives
 byte-for-byte unchanged, i.e. telemetry is free.
 """
+import glob
 import json
 import os
 from types import SimpleNamespace
@@ -199,51 +200,185 @@ def test_obs_off_leaves_collectives_byte_identical():
 
 
 # ---------------------------------------------------------------------------
-# Span tracer
+# Named scopes of the train step (repro.obs.scopes)
 # ---------------------------------------------------------------------------
 
 
-def test_tracer_nesting_and_export_roundtrip(tmp_path):
-    tr = obs_trace.Tracer(enabled=True)
-    with tr.span("outer", step=1) as s:
-        assert isinstance(s, dict)
-        with tr.span("inner"):
-            pass
-        s["tokens"] = 7  # body can attach results to the span args
-    evs = tr.events
-    assert [e["name"] for e in evs] == ["inner", "outer"]  # close order
-    inner, outer = evs
-    assert outer["args"]["depth"] == 0 and inner["args"]["depth"] == 1
-    assert outer["args"]["tokens"] == 7 and outer["args"]["step"] == 1
-    assert outer["dur"] >= inner["dur"] >= 0
+@pytest.mark.tier1
+def test_scopes_leave_optimized_hlo_unchanged():
+    """The scopes are metadata only: the tiny 1x4 expert-parallel train step
+    (the _moe_a2a path) compiled with every scope and with the helper
+    swapped for a nullcontext has the same collective set, byte for byte,
+    once metadata is stripped; indeed the same instructions throughout,
+    up to XLA's numbering of their names."""
+    out = du.run("""
+    import contextlib
+    import re
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.configs.base import reduced
+    from repro.launch.mesh import make_local_mesh
+    from repro.launch.train import jit_train_step, moe_dist
+    from repro.models import lm
+    from repro.obs import scopes
+    from repro.optim.adamw import AdamW
 
-    path = tr.export(str(tmp_path / "trace.json"))
-    back = obs_trace.load_trace(path)
-    assert back["traceEvents"] == evs
-    assert all(e["ph"] == "X" for e in back["traceEvents"])
+    cfg = reduced(get_config("fastmoe-gpt"), num_layers=2, d_model=64)
+    mesh = make_local_mesh(1, 4)
+    assert moe_dist(cfg, mesh, 8 * 32).mode == "a2a"
+    opt = AdamW()
+
+    def optimized():
+        step, _, _ = jit_train_step(cfg, opt, mesh, 8, 32)
+        p = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0),
+                                                  cfg))
+        return step.lower(p, jax.eval_shape(opt.init, p),
+                          {"tokens": jax.ShapeDtypeStruct((8, 32), jnp.int32)},
+                          jax.ShapeDtypeStruct((), jnp.int32)
+                          ).compile().as_text()
+
+    on = optimized()
+    scopes.scope = contextlib.nullcontext
+    off = optimized()
+    assert set(re.findall(r"fmoe[.][a-z_]+", on)) >= set(scopes.ALL)
+    assert not set(re.findall(r"fmoe[.][a-z_]+", off)) & set(scopes.ALL)
+
+    def instructions(txt):
+        # every instruction without its metadata, instruction names
+        # blanked (XLA numbers them in creation order), the debug-info
+        # tables between the module header and the first computation cut
+        txt = re.sub(r"\\nFileNames\\n.*?\\n\\n(?=%|ENTRY)", "\\n", txt,
+                     count=1, flags=re.S)
+        txt = re.sub(r",? ?metadata=[{][^}]*[}]", "", txt)
+        return sorted(re.sub(r"%[\\w.-]+", "%_", line)
+                      for line in txt.splitlines() if " = " in line)
+
+    ins_on, ins_off = instructions(on), instructions(off)
+    coll = re.compile(r"(all-to-all|all-reduce|all-gather|reduce-scatter"
+                      r"|collective-permute)(-start)?[(]")
+    colls = [i for i in ins_on if coll.search(i)]
+    assert colls == [i for i in ins_off if coll.search(i)]
+    assert any("all-to-all(" in c for c in colls), colls
+    assert ins_on == ins_off
+    print("scopes free", len(colls))
+    """, devices=4)
+    assert "scopes free" in out
+
+
+@pytest.mark.tier1
+def test_scope_set_same_on_the_four_moe_paths():
+    """Scopes live in the shared helpers, so _moe_local, _moe_a2a,
+    _moe_a2a_ragged and _moe_psum all name router, dispatch, experts and
+    combine, forward and backward, and the two all-to-all paths also the
+    exchange."""
+    out = du.run("""
+    import re
+    import jax
+    import dist_utils as du
+    from repro.core import fmoe
+
+    mesh = du.make_mesh(1, 4)
+    MOE = {"fmoe.router", "fmoe.dispatch", "fmoe.experts", "fmoe.combine"}
+
+    def scopes_of(env, dist):
+        def loss(p):
+            y, m = fmoe.fmoe_apply(p, env.x, env.cfg, dist=dist)
+            return (y ** 2).mean() + 0.01 * (m.aux_loss + m.z_loss)
+        with mesh:
+            txt = jax.jit(jax.grad(loss)).lower(env.params).compile().as_text()
+        names = re.findall(r'op_name="([^"]*)"', txt)
+        back = {m for n in names if "transpose(" in n
+                for m in re.findall(r"fmoe[.][a-z_]+", n)}
+        return {m for n in names for m in re.findall(r"fmoe[.][a-z_]+", n)
+                } - {"fmoe.py"}, back
+
+    cap, rag = du.moe_env(), du.moe_env(dispatch="ragged")
+    paths = {
+        "local": (cap, None, "local"),
+        "a2a": (cap, fmoe.DistConfig(mesh, ("data", "model")), "a2a"),
+        "ragged": (rag, fmoe.DistConfig(mesh, ("data", "model")), "a2a"),
+        "psum": (cap, fmoe.DistConfig(mesh, ("data",)), "psum"),
+    }
+    for name, (env, dist, mode) in paths.items():
+        assert dist is None or dist.mode == mode, (name, dist.mode)
+        got, back = scopes_of(env, dist)
+        want = MOE | ({"fmoe.exchange"} if name in ("a2a", "ragged")
+                      else set())
+        assert got == want, (name, got)
+        assert back >= MOE - {"fmoe.dispatch"}, (name, back)
+    print("scope sets ok")
+    """, devices=4)
+    assert "scope sets ok" in out
+
+
+# ---------------------------------------------------------------------------
+# Host spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _host_spans(log_dir: str, names) -> list:
+    """[(name, start, end)] of the host-plane events called one of ``names``
+    in the profiler trace under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert len(paths) == 1, paths
+    out = []
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.end_ns) for e in line.events
+                        if e.name in names]
+    return sorted(out, key=lambda ev: ev[1])
+
+
+def test_tracer_nesting_and_export_roundtrip(tmp_path):
+    """Spans on: each is a TraceAnnotation in the profiler trace's host
+    plane, nested as entered, on the trace's one clock."""
+    assert not obs_trace.enabled()  # off by default
+    log_dir = str(tmp_path / "trace")
+    f = jax.jit(lambda x: x * 2)
+    try:
+        obs_trace.configure(enabled=True)
+        jax.profiler.start_trace(log_dir)
+        with obs_trace.span("outer", step=1):
+            with obs_trace.span("inner"):
+                f(jnp.ones(3)).block_until_ready()
+        jax.profiler.stop_trace()
+    finally:
+        obs_trace.configure(enabled=False)
+    spans = _host_spans(log_dir, {"outer", "inner"})
+    assert [n for n, _, _ in spans] == ["outer", "inner"]
+    (_, os_, oe), (_, is_, ie) = spans
+    assert os_ <= is_ < ie <= oe
 
 
 def test_tracer_disabled_is_noop_and_ring_bounded(tmp_path):
-    tr = obs_trace.Tracer(enabled=False)
-    with tr.span("x") as s:
+    """Spans off: a no-op that leaves nothing in a running trace.  The
+    CLIs' Window records exactly its steps."""
+    log_dir = str(tmp_path / "off")
+    jax.profiler.start_trace(log_dir)
+    with obs_trace.span("x", step=0) as s:
         assert s is None
-    assert tr.events == []
+    jax.profiler.stop_trace()
+    assert _host_spans(log_dir, {"x"}) == []
 
-    tr = obs_trace.Tracer(enabled=True, max_events=3)
-    for i in range(10):
-        with tr.span(f"s{i}"):
-            pass
-    assert [e["name"] for e in tr.events] == ["s7", "s8", "s9"]
-
-    # module-level singleton: disabled by default, one configure() lights it
-    assert not obs_trace.enabled()
+    log_dir = str(tmp_path / "window")
+    w = obs_trace.Window(log_dir, first=2, steps=2)
     try:
-        obs_trace.configure(enabled=True, max_events=16)
-        with obs_trace.span("global"):
-            pass
-        assert [e["name"] for e in obs_trace.get().events] == ["global"]
+        obs_trace.configure(enabled=True)
+        for step in range(6):
+            w.at(step)
+            with obs_trace.span(f"s{step}"):
+                pass
+        w.close()
     finally:
         obs_trace.configure(enabled=False)
+    got = _host_spans(log_dir, {f"s{i}" for i in range(6)})
+    assert [n for n, _, _ in got] == ["s2", "s3"]
+    assert not w.on
 
 
 # ---------------------------------------------------------------------------
@@ -388,9 +523,10 @@ def test_serve_step_with_metrics_single_device():
 @pytest.mark.tier1
 def test_train_cli_metrics_out_and_trace(tmp_path):
     """--metrics_out/--trace end to end on a 1x2 mesh: per-step JSONL
-    records carrying the device wire counters + a loadable Chrome trace."""
+    records carrying the device wire counters + a profiler trace whose host
+    plane holds the train_step span of the step after the first."""
     mpath = str(tmp_path / "metrics.jsonl")
-    tpath = str(tmp_path / "trace.json")
+    tpath = str(tmp_path / "trace")
     out = du.run_cli(
         ["repro.launch.train", "--arch", "fastmoe-gpt", "--reduced",
          "--steps", "2", "--batch", "4", "--seq", "32", "--mesh", "1x2",
@@ -409,8 +545,7 @@ def test_train_cli_metrics_out_and_trace(tmp_path):
         # modeled HLO bytes rode along (AOT-lowered step)
         assert any(k.startswith("modeled_") for k in r)
 
-    trace = obs_trace.load_trace(tpath)
-    names = {e["name"] for e in trace["traceEvents"]}
-    assert "train_step" in names
-    assert all(e["ph"] == "X" and e["dur"] >= 0
-               for e in trace["traceEvents"])
+    spans = _host_spans(tpath, {"train_step"})
+    assert len(spans) == 1 and spans[0][2] > spans[0][1]
+    assert glob.glob(os.path.join(tpath, "plugins", "profile", "*",
+                                  "perfetto_trace.json.gz"))

@@ -220,12 +220,19 @@ def _flat_paths(tree, prefix=""):
         yield prefix[:-1], tree
 
 
+# A leaf is stacked by layer wherever a ``layers``/``enc_layers`` segment
+# sits in its path, so a tree that nests the params (AdamW's ``mu``/``nu``)
+# gets the params' own specs; a moment in another layout is resharded
+# inside every optimizer update.
+_STACKED = re.compile(r"(^|/)(enc_)?layers/")
+
+
 def tree_specs(tree, mesh, mode: str = "train", cfg=None) -> Any:
     """PartitionSpec pytree mirroring ``tree`` (abstract or concrete)."""
     flat = dict(_flat_paths(tree))
     rules = rules_for(cfg, mesh) if cfg is not None else None
     specs = {p: spec_for(p, v.shape, mesh, mode=mode, rules=rules,
-                         stacked=p.startswith(("layers/", "enc_layers/")))
+                         stacked=bool(_STACKED.search(p)))
              for p, v in flat.items()}
     return _rebuild(tree, specs, "")
 

@@ -7,10 +7,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
-from repro.configs import get_config, reduced
+from repro.configs import ARCHS, get_config, reduced
 from repro.core.sync import fastmoe_tag, grad_sync_axes, spec_axes
 from repro.launch.sharding import _flat_paths, spec_for, tree_specs
 from repro.models import lm
+from repro.optim.adamw import AdamW
 
 
 def _mesh(shape=(16, 16), axes=("data", "model")):
@@ -147,3 +148,26 @@ def test_sync_report_covers_three_tags():
     flat_specs = dict(_flat_paths(tree_specs(shapes, mesh)))
     tags = {fastmoe_tag(p, s, ("data", "model")) for p, s in flat_specs.items()}
     assert tags == {"world", "dp", "none"}
+
+
+@pytest.mark.parametrize("mesh_shape,mesh_axes", [
+    ((1, 4), ("data", "model")),
+    ((2, 16, 16), ("pod", "data", "model")),
+], ids=["1x4", "2x16x16"])
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_adamw_moments_get_param_specs(arch, mesh_shape, mesh_axes):
+    """Each AdamW moment leaf ``mu/<p>``, ``nu/<p>`` takes exactly the spec
+    of the parameter ``<p>`` it mirrors (a stacked moment keeps its leading
+    layer dim unsharded), and the step counter is replicated: a moment in
+    another layout would be resharded inside every optimizer update."""
+    cfg = get_config(arch)
+    mesh = _mesh(mesh_shape, mesh_axes)
+    params = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(AdamW().init, params)
+    pspecs = dict(_flat_paths(tree_specs(params, mesh)))
+    ospecs = dict(_flat_paths(tree_specs(state, mesh)))
+    assert ospecs.pop("step") == P()
+    assert set(ospecs) == {f"{m}/{p}" for m in ("mu", "nu") for p in pspecs}
+    for path, spec in pspecs.items():
+        assert ospecs[f"mu/{path}"] == spec, (path, ospecs[f"mu/{path}"], spec)
+        assert ospecs[f"nu/{path}"] == spec, (path, ospecs[f"nu/{path}"], spec)

@@ -73,13 +73,14 @@ def _devices(chips: int, chip_check: bool):
     return devs[:chips]
 
 
-def _leaf_norms(tree, scale: float = 1.0) -> dict:
-    """Each leaf's norm; a leaf stacked over layers, one norm per layer."""
+def _leaf_norms(tree, stacked, scale: float = 1.0) -> dict:
+    """Each leaf's norm; a leaf in ``stacked`` (stacked over layers along
+    axis 0), one norm per layer."""
     import jax.numpy as jnp
 
     out = {}
     for p, a in model.flatten(tree).items():
-        axes = tuple(range(1 if p.startswith("layers/") else 0, a.ndim))
+        axes = tuple(range(1 if p in stacked else 0, a.ndim))
         out[p] = jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32)),
                                   axis=axes)) * scale
     return out
@@ -118,6 +119,13 @@ class _CompileCounter:
             self.counts[event] = self.counts.get(event, 0) + 1
 
 
+def flops_per_token(cell: spec.Cell) -> int:
+    """Model FLOPs per trained token, as the configuration's reference
+    counts them at the cell's sequence length."""
+    return cell.reference().flops_per_token(cell.config,
+                                            cell.traffic["seq_len"])
+
+
 def build_program(cell: spec.Cell, devs) -> Program:
     """The program's own train step for the cell, as its CLI builds it."""
     import jax
@@ -128,22 +136,21 @@ def build_program(cell: spec.Cell, devs) -> Program:
     from repro.launch.sharding import batch_spec
 
     conf, mix = cell.config, cell.traffic
+    ref = cell.reference()
     cfg = model.program_config(conf)
-    model.check_tree(conf, cfg)
-    model.check_program_defaults(cfg, conf)
-    opt = model.make_optimizer(conf)
     mb = mix["microbatches"]
-    if cell.chips == 1:
+    mesh = make_local_mesh(1, cell.chips) if cell.chips > 1 else None
+    dist = (None if mesh is None else
+            moe_dist(cfg, mesh, mix["batch"] * mix["seq_len"] // mb))
+    ref.covers(cfg, conf, cell.chips, dist)
+    model.check_tree(ref.param_shapes(conf), cfg)
+    model.check_optimizer_defaults(conf)
+    opt = model.make_optimizer(conf)
+    if mesh is None:
         step = jax.jit(make_train_step(cfg, opt, num_microbatches=mb),
                        donate_argnums=(0, 1))
         one = SingleDeviceSharding(devs[0])
         return Program(cfg, opt, step, one, one, one, 1, None)
-    mesh = make_local_mesh(1, cell.chips)
-    dist = moe_dist(cfg, mesh, mix["batch"] * mix["seq_len"] // mb)
-    if dist is None or dist.mode != "a2a" or len(dist.token_axes) != 2:
-        raise ValueError(f"the reference covers the all-to-all expert path "
-                         f"with tokens over every chip; the program chose "
-                         f"{dist}")
     step, pshard, oshard = jit_train_step(cfg, opt, mesh, mix["batch"],
                                           mix["seq_len"], num_microbatches=mb)
     bshard = NamedSharding(mesh, batch_spec(mix["batch"], mesh))
@@ -157,7 +164,8 @@ def start(cell: spec.Cell, prog: Program, seed: int):
 
     conf = cell.config
     key = model.seed_key(seed)
-    params = jax.jit(functools.partial(model.init_params, conf),
+    params = jax.jit(functools.partial(model.init_params,
+                                       cell.reference().param_shapes(conf)),
                      out_shardings=prog.pshard)(key)
     opt_state = jax.jit(prog.opt.init, out_shardings=prog.oshard)(params)
     pool_host = traffic.make_pool(cell.traffic, conf["vocab_size"], seed)
@@ -174,11 +182,13 @@ def first_steps(cell: spec.Cell, prog: Program, key, params, opt_state,
     import jax
     import jax.numpy as jnp
 
-    conf = cell.config
-    grad_norms = jax.jit(functools.partial(_leaf_norms,
+    ref = cell.reference()
+    shapes = ref.param_shapes(cell.config)
+    stacked = set(ref.layer_leaves(cell.config))
+    grad_norms = jax.jit(functools.partial(_leaf_norms, stacked=stacked,
                                            scale=1.0 / (1 - prog.opt.b1)))
     delta_norms = jax.jit(lambda p, k: _leaf_norms(jax.tree.map(
-        jnp.subtract, p, model.init_params(conf, k))))
+        jnp.subtract, p, model.init_params(shapes, k)), stacked))
     losses, grad = [], None
     for i in range(CORRECT_STEPS):
         params, opt_state, m = prog.step(params, opt_state, pool[i],
@@ -287,18 +297,20 @@ def run_reference(cell: spec.Cell, seed: int, pool_host, groups: int, mesh,
     conf, mix = cell.config, cell.traffic
     ref = cell.reference()
     dot = ref.make_dot(quant)
+    shapes = ref.param_shapes(conf)
+    stacked = set(ref.layer_leaves(conf))
     constrain = lambda a: a
     pshard = None
     if mesh is not None:
-        # experts over the chips along the expert dim, the rest replicated
+        # experts over the chips along each leaf's expert axis, as the
+        # reference names it; the rest replicated
         from jax.sharding import Mesh
         emesh = Mesh(np.asarray(mesh.devices).reshape(-1), ("e",))
-        rep = NamedSharding(emesh, P())
-        esh = NamedSharding(emesh, P(None, "e"))
-        shapes = model.param_shapes(conf)
-        pshard = jax.tree.map(lambda _: rep, shapes,
-                              is_leaf=lambda s: isinstance(s, tuple))
-        pshard["layers"]["ffn"]["experts"] = {"wi": esh, "wo": esh}
+        experts = ref.expert_leaves(conf)
+        pshard = model.unflatten({
+            p: NamedSharding(emesh, P(*[None] * experts[p], "e")
+                             if p in experts else P())
+            for p in model.flatten(shapes)})
         buf = NamedSharding(emesh, P("e"))
         constrain = lambda a: jax.lax.with_sharding_constraint(a, buf)
     o = conf["optimizer"]
@@ -307,10 +319,10 @@ def run_reference(cell: spec.Cell, seed: int, pool_host, groups: int, mesh,
 
     def step(p, mu, nu, batch, t, lr):
         p, mu, nu, loss, g = train(p, mu, nu, batch, t, lr)
-        return p, mu, nu, loss, _leaf_norms(g)
+        return p, mu, nu, loss, _leaf_norms(g, stacked)
 
     key = model.seed_key(seed)
-    init = jax.jit(functools.partial(model.init_params, conf),
+    init = jax.jit(functools.partial(model.init_params, shapes),
                    out_shardings=pshard)
     zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p),
                     out_shardings=pshard)
@@ -330,7 +342,7 @@ def run_reference(cell: spec.Cell, seed: int, pool_host, groups: int, mesh,
                 grad, grad_layers = _host(g)
         del mu, nu
         delta = jax.jit(lambda p, k: _leaf_norms(jax.tree.map(
-            jnp.subtract, p, model.init_params(conf, k))))
+            jnp.subtract, p, model.init_params(shapes, k)), stacked))
         update, update_layers = _host(delta(params, key))
     return {"loss": losses, "grad": grad, "update": update,
             "grad_layers": grad_layers, "update_layers": update_layers}
@@ -375,8 +387,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
         run_data = RunData(
             chips=len(devs), steps=steps,
             tokens_per_s=r["tokens_per_s"],
-            flops_per_token=flops.flops_per_token(
-                cell.config, cell.traffic["seq_len"]),
+            flops_per_token=flops_per_token(cell),
             peaks=flops.peaks(devs[0].device_kind,
                               os.path.join(cell.bench_dir, "peaks.json")),
             aux=r["aux"],

@@ -1,9 +1,12 @@
 """A configuration file -> the program's ModelConfig, and the cell's weights.
 
-The weights are the benchmark's own: made from the seed on the device in one
-jitted call, laid out as the program's parameter tree (checked against the
-program's own ``init_params`` shapes), so the plain reference starts from the
-same numbers without taking anything the program made.
+Nothing here knows a model: the tree of leaf shapes, what the reference
+covers and the FLOPs per token come from the configuration's reference
+module (``bench/reference/<reference>.py``).  The weights are the
+benchmark's own: made from the seed on the device in one jitted call, laid
+out as that tree (checked against the program's own ``init_params``
+shapes), so the plain reference starts from the same numbers without taking
+anything the program made.
 """
 from __future__ import annotations
 
@@ -11,70 +14,76 @@ import dataclasses
 
 import numpy as np
 
-# file key -> (ModelConfig group, field); None = a top-level field
-PROGRAM_KEYS = {
-    "num_layers": (None, "num_layers"),
-    "d_model": (None, "d_model"),
-    "vocab_size": (None, "vocab_size"),
-    "norm": (None, "norm"),
-    "act": (None, "act"),
-    "tie_embeddings": (None, "tie_embeddings"),
-    "dtype": (None, "dtype"),
-    "param_dtype": (None, "param_dtype"),
-    "num_heads": ("attention", "num_heads"),
-    "num_kv_heads": ("attention", "num_kv_heads"),
-    "head_dim": ("attention", "head_dim"),
-    "rope_theta": ("attention", "rope_theta"),
-    "num_experts": ("moe", "num_experts"),
-    "top_k": ("moe", "top_k"),
-    "d_expert_hidden": ("moe", "d_expert_hidden"),
-    "capacity_factor": ("moe", "capacity_factor"),
-    "gate_policy": ("moe", "gate_policy"),
-    "renormalize": ("moe", "renormalize"),
-    "balance_loss_weight": ("moe", "balance_loss_weight"),
-    "z_loss_weight": ("moe", "z_loss_weight"),
-}
+# Keys of a configuration file that describe the file, not the program.
+METADATA_KEYS = frozenset({"name", "source", "program_arch", "reference",
+                           "deployment", "reduced", "assumed", "optimizer"})
+
+
+def _program_fields(cfg, keys) -> dict:
+    """{key: group} of each of ``keys`` that names a program field: a field
+    of ModelConfig itself (group None) or of a sub-config the registered
+    config has.  A key that names fields of two groups is an error."""
+    groups = [None] + [f.name for f in dataclasses.fields(cfg)
+                       if dataclasses.is_dataclass(getattr(cfg, f.name))]
+    owners: dict = {}
+    for group in groups:
+        for f in dataclasses.fields(getattr(cfg, group) if group else cfg):
+            if f.name not in groups and f.name not in METADATA_KEYS:
+                owners.setdefault(f.name, []).append(group)
+    twice = sorted(k for k in keys if len(owners.get(k, ())) > 1)
+    if twice:
+        raise ValueError(f"{cfg.name}: {twice} each name a field of more "
+                         f"than one group of the program's config")
+    return {k: owners[k][0] for k in keys if k in owners}
+
+
+def _field(cfg, group, name):
+    return getattr(getattr(cfg, group) if group else cfg, name)
+
+
+def _as_program(value):
+    return tuple(value) if isinstance(value, list) else value
 
 
 def program_config(conf: dict):
     """The program's registered config with the file's ``reduced`` keys
-    applied; every other key the file states must already agree."""
+    applied; every other program field the file states must agree.
+
+    A program field is a field of ``ModelConfig`` or of a sub-config the
+    registered config has (``attention``, ``moe``, ...), by name; the file's
+    other keys (``METADATA_KEYS``, notes) are not compared.  A ``reduced``
+    key that names no program field is an error."""
     from repro.configs import get_config
 
     cfg = get_config(conf["program_arch"])
+    fields = _program_fields(cfg, set(conf) | set(conf["reduced"]))
+    stray = sorted(set(conf["reduced"]) - set(fields))
+    if stray:
+        raise ValueError(f"{conf['name']}: reduced keys {stray} name no "
+                         f"field of the program's {conf['program_arch']}")
     groups: dict = {}
     top: dict = {}
-    for key, (group, field) in PROGRAM_KEYS.items():
-        if key in conf["reduced"]:
-            (groups.setdefault(group, {}) if group else top)[field] = conf[key]
-    for group, fields in groups.items():
-        top[group] = dataclasses.replace(getattr(cfg, group), **fields)
+    for key in conf["reduced"]:
+        group = fields[key]
+        (groups.setdefault(group, {}) if group else top)[key] = \
+            _as_program(conf[key])
+    for group, changed in groups.items():
+        top[group] = dataclasses.replace(getattr(cfg, group), **changed)
     cfg = dataclasses.replace(cfg, **top)
-    for key, (group, field) in PROGRAM_KEYS.items():
-        have = getattr(getattr(cfg, group) if group else cfg, field)
-        if have != conf[key]:
+    for key in sorted(set(conf) & set(fields)):
+        have = _field(cfg, fields[key], key)
+        if have != _as_program(conf[key]):
             raise ValueError(f"{conf['name']}: the program's "
                              f"{conf['program_arch']} has {key}={have!r}, "
                              f"the configuration file states {conf[key]!r}")
-    if cfg.family != "moe" or cfg.attention.kind != "gqa" or cfg.frontend != "none":
-        raise ValueError(f"{conf['name']}: the moe_lm reference covers "
-                         f"decoder-only GQA MoE models only")
     return cfg
 
 
-def check_program_defaults(cfg, conf: dict) -> None:
-    """The reference follows the routing and optimizer the cell runs."""
+def check_optimizer_defaults(conf: dict) -> None:
+    """The reference follows the schedule the program's step runs."""
     import inspect
 
     from repro.launch.train import make_train_step
-    moe = cfg.moe
-    if moe.router != "topk" or moe.dispatch != "capacity":
-        raise ValueError(f"the moe_lm reference covers top-k capacity "
-                         f"routing; the program's default is router="
-                         f"{moe.router!r} dispatch={moe.dispatch!r}")
-    if moe.num_shared_experts or moe.dense_residual or moe.router_dtype != "float32":
-        raise ValueError("the moe_lm reference has no shared or dense "
-                         "residual experts and routes in float32")
     sig = inspect.signature(make_train_step).parameters
     o = conf["optimizer"]
     for k in ("warmup", "total_steps"):
@@ -101,29 +110,6 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, np.uint32((seed >> 32) & 0xFFFFFFFF))
 
 
-def param_shapes(conf: dict) -> dict:
-    """Leaf shapes of the parameter tree, program layout."""
-    L, d, V = conf["num_layers"], conf["d_model"], conf["vocab_size"]
-    H, KV, hd = conf["num_heads"], conf["num_kv_heads"], conf["head_dim"]
-    E, Hx = conf["num_experts"], conf["d_expert_hidden"]
-    norm = {"scale": (L, d)}
-    if conf["norm"] == "layernorm":
-        norm["bias"] = (L, d)
-    final = {k: v[1:] for k, v in norm.items()}
-    return {
-        "embed": {"table": (V, d)},
-        "layers": {
-            "norm1": dict(norm), "norm2": dict(norm),
-            "attn": {"wq": {"w": (L, d, H * hd)}, "wk": {"w": (L, d, KV * hd)},
-                     "wv": {"w": (L, d, KV * hd)}, "wo": {"w": (L, H * hd, d)}},
-            "ffn": {"router": {"w": (L, d, E)},
-                    "experts": {"wi": (L, E, d, Hx), "wo": (L, E, Hx, d)}},
-        },
-        "final_norm": final,
-        "lm_head": {"w": (d, V)},
-    }
-
-
 def _leaf_init(path: str, shape: tuple, key):
     """Normal weights at the program's scales; norm scales 1, biases 0."""
     import jax
@@ -138,19 +124,20 @@ def _leaf_init(path: str, shape: tuple, key):
     return jax.random.normal(key, shape, jnp.float32) * std
 
 
-def init_params(conf: dict, key) -> dict:
-    """The cell's float32 weights from ``key``; trace it under ``jax.jit``."""
+def init_params(shapes: dict, key) -> dict:
+    """Float32 weights of the tree of leaf shapes ``shapes`` from ``key``;
+    trace it under ``jax.jit``."""
     import jax
 
-    shapes = param_shapes(conf)
     flat = flatten(shapes)
     leaves = {p: _leaf_init(p, s, jax.random.fold_in(key, i))
               for i, (p, s) in enumerate(sorted(flat.items()))}
-    return _unflatten(leaves)
+    return unflatten(leaves)
 
 
-def check_tree(conf: dict, cfg) -> None:
-    """The benchmark's tree matches the program's ``init_params`` exactly."""
+def check_tree(shapes: dict, cfg) -> None:
+    """The reference's tree of leaf shapes matches the program's
+    ``init_params`` exactly."""
     import jax
     import jax.numpy as jnp
 
@@ -158,7 +145,7 @@ def check_tree(conf: dict, cfg) -> None:
     want = jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0), cfg))
     want = {p: (tuple(a.shape), a.dtype) for p, a in flatten(want).items()}
     have = {p: (tuple(s), jnp.dtype(jnp.float32))
-            for p, s in flatten(param_shapes(conf)).items()}
+            for p, s in flatten(shapes).items()}
     if want != have:
         raise ValueError(f"parameter tree differs from the program's: "
                          f"{sorted(set(want.items()) ^ set(have.items()))}")
@@ -176,7 +163,7 @@ def flatten(tree, prefix: str = "") -> dict:
     return out
 
 
-def _unflatten(flat: dict) -> dict:
+def unflatten(flat: dict) -> dict:
     tree: dict = {}
     for path, v in flat.items():
         node = tree

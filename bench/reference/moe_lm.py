@@ -26,6 +26,20 @@ Every matmul runs in float32 at ``Precision.HIGHEST``.  ``quant`` replaces
 that with a control: every matmul's operands (and, in the backward pass,
 its cotangents) rounded to float8 e4m3 or to int8, each under a per-tensor
 scale.
+
+A reference module owns its model's description; the harness names no leaf,
+attention kind or MoE option and takes these from it:
+
+- ``param_shapes(conf)``: the tree of leaf shapes in the program's layout;
+- ``layer_leaves(conf)``: the leaves whose axis 0 counts layers (their norms
+  are read per layer);
+- ``expert_leaves(conf)``: each leaf with an expert axis, and that axis, along
+  which it is sharded when the reference runs on several chips;
+- ``covers(cfg, conf, chips, dist)``: raises where the program's registered
+  config, its defaults or its expert distribution lie outside what this
+  reference computes;
+- ``flops_per_token(conf, seq_len)``: model FLOPs per trained token;
+- ``make_dot``, ``QUANT``, ``make_train_step`` and ``lr_scale``.
 """
 from __future__ import annotations
 
@@ -37,7 +51,93 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import flops, model
+
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# The keys a configuration file for this reference states: the sizes and
+# options it reads, and the precision and tying the program runs with.
+REQUIRED = ("num_layers", "d_model", "vocab_size", "norm", "act",
+         "tie_embeddings", "dtype", "param_dtype", "num_heads",
+         "num_kv_heads", "head_dim", "rope_theta", "num_experts", "top_k",
+         "d_expert_hidden", "capacity_factor", "gate_policy", "renormalize",
+         "balance_loss_weight", "z_loss_weight")
+
+
+def param_shapes(conf: dict) -> dict:
+    """Leaf shapes of the parameter tree, program layout."""
+    L, d, V = conf["num_layers"], conf["d_model"], conf["vocab_size"]
+    H, KV, hd = conf["num_heads"], conf["num_kv_heads"], conf["head_dim"]
+    E, Hx = conf["num_experts"], conf["d_expert_hidden"]
+    norm = {"scale": (L, d)}
+    if conf["norm"] == "layernorm":
+        norm["bias"] = (L, d)
+    final = {k: v[1:] for k, v in norm.items()}
+    return {
+        "embed": {"table": (V, d)},
+        "layers": {
+            "norm1": dict(norm), "norm2": dict(norm),
+            "attn": {"wq": {"w": (L, d, H * hd)}, "wk": {"w": (L, d, KV * hd)},
+                     "wv": {"w": (L, d, KV * hd)}, "wo": {"w": (L, H * hd, d)}},
+            "ffn": {"router": {"w": (L, d, E)},
+                    "experts": {"wi": (L, E, d, Hx), "wo": (L, E, Hx, d)}},
+        },
+        "final_norm": final,
+        "lm_head": {"w": (d, V)},
+    }
+
+
+def layer_leaves(conf: dict) -> list:
+    """Leaves stacked over layers along axis 0: everything under ``layers``."""
+    return [p for p in model.flatten(param_shapes(conf))
+            if p.startswith("layers/")]
+
+
+def expert_leaves(conf: dict) -> dict:
+    """Leaves with an expert axis, and that axis: the experts' two
+    matrices, stacked over layers, so axis 1."""
+    return {"layers/ffn/experts/wi": 1, "layers/ffn/experts/wo": 1}
+
+
+def covers(cfg, conf: dict, chips: int = 1, dist=None) -> None:
+    """Raise where the program's config ``cfg``, its defaults or its expert
+    distribution ``dist`` over ``chips`` chips lie outside this reference:
+    a decoder-only GQA model with gelu top-k experts in every layer, routed
+    in float32 with capacity dispatch, no shared or dense residual experts,
+    and on several chips the expert all-to-all with tokens over every chip.
+    """
+    name = conf["name"]
+    missing = [k for k in REQUIRED if k not in conf]
+    if missing:
+        raise ValueError(f"{name}: the configuration file does not state "
+                         f"{missing}, which the moe_lm reference needs")
+    attn = cfg.attention
+    if (cfg.family != "moe" or attn is None or attn.kind != "gqa"
+            or cfg.frontend != "none"):
+        raise ValueError(f"{name}: the moe_lm reference covers "
+                         f"decoder-only GQA MoE models only")
+    if cfg.act != "gelu" or attn.qkv_bias or attn.sliding_window is not None:
+        raise ValueError(f"{name}: the moe_lm reference has gelu experts and "
+                         f"full attention without biases")
+    moe = cfg.moe
+    if moe.router != "topk" or moe.dispatch != "capacity":
+        raise ValueError(f"the moe_lm reference covers top-k capacity "
+                         f"routing; the program's default is router="
+                         f"{moe.router!r} dispatch={moe.dispatch!r}")
+    if moe.num_shared_experts or moe.dense_residual or moe.router_dtype != "float32":
+        raise ValueError("the moe_lm reference has no shared or dense "
+                         "residual experts and routes in float32")
+    if chips > 1 and (dist is None or dist.mode != "a2a"
+                      or len(dist.token_axes) != 2):
+        raise ValueError(f"the moe_lm reference covers the all-to-all expert "
+                         f"path with tokens over every chip; the program "
+                         f"chose {dist}")
+
+
+def flops_per_token(conf: dict, seq_len: int) -> int:
+    """PaLM's count (``bench/flops.py``): attention projections, the top-k
+    experts, the router and the head, and the attention scores."""
+    return flops.flops_per_token(conf, seq_len)
 
 
 def fp8_e4m3(a: jax.Array) -> jax.Array:

@@ -2,7 +2,9 @@
 cell limit is a file of its own, found by the name ``BENCHMARK.json`` gives.
 
 - configuration ``<c>``: the ``file`` of its ``configs`` entry (JSON sizes),
-  whose ``reference`` key names ``bench/reference/<reference>.py``;
+  whose ``reference`` key names ``bench/reference/<reference>.py``, the
+  plain reference that also owns the model's parameter tree, what it covers
+  and its FLOPs per token;
 - traffic mix ``<t>``: ``bench/traffic/<t>.json``;
 - per-layer metric ``<m>``: ``bench/metrics/<m>.py``;
 - cell ``<w>``: its comparison limits in ``bench/limits/<w>.json``.
@@ -15,7 +17,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -49,14 +51,19 @@ class Cell:
     per_layer: list  # metric entries this cell reports with --trace 1
     reference_path: str
     bench_dir: str
+    _reference: Any = field(default=None, repr=False, compare=False)
 
     def metric_reader(self, name: str):
         return _load_module(os.path.join(self.bench_dir, "metrics",
                                          f"{name}.py"), f"bench_metric_{name}")
 
     def reference(self):
-        return _load_module(self.reference_path,
-                            "bench_reference_" + self.config["reference"])
+        """The configuration's reference module, loaded once per cell."""
+        if self._reference is None:
+            self._reference = _load_module(
+                self.reference_path,
+                "bench_reference_" + self.config["reference"])
+        return self._reference
 
 
 def _applies(metric: dict, workload: str) -> bool:

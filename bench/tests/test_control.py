@@ -18,3 +18,14 @@ def test_control_fails_where_the_program_passes(variant, tmp_path):
     for row in rows:
         ok, _ = compare.judge(row["readings"], tiny.LIMITS)
         assert ok == (row["variant"] == "program"), row
+
+
+def test_int8_control_fails_on_the_top2_path(tmp_path):
+    """gpt.train.l1's control, on one chip with FastMoE's top-2 routing."""
+    bench = tiny.make(str(tmp_path), arch="fastmoe-gpt", top_k=2)
+    rows = control.sweep(str(tmp_path), "tiny.train", SEEDS,
+                         ["program", "int8"], chip_check=False,
+                         compile_cache=False, bench_dir=bench)
+    for row in rows:
+        ok, _ = compare.judge(row["readings"], tiny.LIMITS)
+        assert ok == (row["variant"] == "program"), row

@@ -4,7 +4,7 @@ import os
 
 import pytest
 
-from bench import flops
+from bench import flops, harness, spec
 from bench.tests import tiny
 
 
@@ -30,6 +30,17 @@ def test_gpt_l1_hand_count():
     # the Open question's one-chip cut: 1 layer, vocab 50304 / 8 = 6288
     conf = dict(_conf("fastmoe-gpt.ep4.json"), num_layers=1, vocab_size=6288)
     assert round(flops.flops_per_token(conf, 1024) / 1e5) == 1273
+
+
+@pytest.mark.parametrize("workload, count", [
+    ("switch.train.l1", 66_281_472),
+    ("gpt.train.ep4", 663_748_608),
+    # per layer 12,681,216 + head 1024*6288 -> 6N = 114,720,768;
+    # + 12*1*16*64*1024 = 12,582,912
+    ("gpt.train.l1", 127_303_680),
+])
+def test_each_cell_counted_by_its_reference(workload, count):
+    assert harness.flops_per_token(spec.resolve(tiny.REPO, workload)) == count
 
 
 def test_peaks_by_device_kind():

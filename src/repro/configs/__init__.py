@@ -17,6 +17,7 @@ from repro.configs.whisper_tiny import CONFIG as _whisper
 from repro.configs.arctic_480b import CONFIG as _arctic
 from repro.configs.qwen2_72b import CONFIG as _qwen2
 from repro.configs.deepseek_v2_236b import CONFIG as _deepseek
+from repro.configs.deepseek_v2_lite import CONFIG as _deepseek_lite
 from repro.configs.hymba_1_5b import CONFIG as _hymba
 from repro.configs.rwkv6_7b import CONFIG as _rwkv6
 from repro.configs.smollm_360m import CONFIG as _smollm
@@ -30,7 +31,7 @@ ARCHS: dict[str, ModelConfig] = {
     for c in [
         _granite, _whisper, _arctic, _qwen2, _deepseek,
         _hymba, _rwkv6, _smollm, _internvl, _starcoder2,
-        _fastmoe_gpt, _fastmoe_dense, _switch,
+        _fastmoe_gpt, _fastmoe_dense, _switch, _deepseek_lite,
     ]
 }
 

@@ -8,6 +8,7 @@ so they are hashable (usable as jit static args) and purely declarative —
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -33,14 +34,50 @@ class AttentionConfig:
     global_layers: Tuple[int, ...] = ()
     # --- MLA-only fields (DeepSeek-V2) ---
     kv_lora_rank: int = 0  # compressed KV latent width (512 for DS-V2)
-    q_lora_rank: int = 0  # 0 => full-rank Q projection
+    q_lora_rank: Optional[int] = 0  # 0 or None => full-rank Q projection
     qk_rope_head_dim: int = 64  # decoupled RoPE key width
     qk_nope_head_dim: int = 128
     v_head_dim: int = 128
+    # --- MLA-only: YaRN context extension (DeepSeek-V2's rope_scaling) ---
+    # A factor of 1 keeps plain rope.  Above 1 the rotary frequencies blend
+    # the original ones with ones interpolated by the factor over the
+    # beta_fast..beta_slow rotation ramp of the original context, and the
+    # attention softmax scale is multiplied by mscale(mscale_all_dim)^2.
+    yarn_factor: float = 1.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
+
+    @property
+    def yarn(self) -> Optional[tuple]:
+        """(factor, original context, beta_fast, beta_slow, cos/sin scale)
+        for ``models.layers.rope_freqs``, or None for plain rope."""
+        if self.yarn_factor <= 1.0:
+            return None
+        return (self.yarn_factor, self.yarn_original_max_position,
+                self.yarn_beta_fast, self.yarn_beta_slow,
+                yarn_mscale(self.yarn_factor, self.yarn_mscale)
+                / yarn_mscale(self.yarn_factor, self.yarn_mscale_all_dim))
+
+    @property
+    def softmax_scale_factor(self) -> float:
+        """YaRN's factor on the 1/sqrt(d) attention scale (1 without)."""
+        if self.yarn_factor <= 1.0 or not self.yarn_mscale_all_dim:
+            return 1.0
+        return yarn_mscale(self.yarn_factor, self.yarn_mscale_all_dim) ** 2
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclass(frozen=True)
@@ -89,6 +126,15 @@ class MoEConfig:
     # supports expert parallelism) | "ragged" (sorted tokens + grouped GEMM,
     # FastMoE-faithful single-worker path, no token drops)
     dispatch: str = "capacity"
+    # routed experts this chip holds: experts 0 .. experts_held-1, one
+    # chip's share of an expert-parallel layer run without its exchange
+    # (the router still scores all num_experts); 0 => all of them
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this layer holds and computes."""
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -110,7 +156,10 @@ class ModelConfig:
     name: str = "model"
     family: str = "dense"  # dense | moe | ssm | hybrid | audio | vlm
     source: str = ""  # citation for the assigned config
-    num_layers: int = 2
+    num_layers: int = 2  # all layers, leading dense ones included
+    # leading dense-FFN layers (width d_ff) before the MoE stack
+    # (DeepSeek's first_k_dense_replace); they count in num_layers
+    num_dense_layers: int = 0
     d_model: int = 256
     d_ff: int = 1024
     vocab_size: int = 32000
@@ -129,7 +178,8 @@ class ModelConfig:
     # numerics
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
-    # remat policy for the scanned layer stack: "full" | "none"
+    # remat policy for the scanned layer stack: "full" | "none"; "full" also
+    # recomputes each attention KV chunk's scores in the backward pass
     remat: str = "full"
 
     # -- derived ------------------------------------------------------------
@@ -137,12 +187,19 @@ class ModelConfig:
     def ffn_kind(self) -> str:
         return "moe" if self.moe is not None else "dense"
 
+    @property
+    def stack_layers(self) -> int:
+        """Layers of the scanned ``layers`` stack: those after the leading
+        dense ones (every layer when there are none)."""
+        return self.num_layers - self.num_dense_layers
+
     def param_count(self) -> int:
         """Total parameter count (embedding + layers + head)."""
         n = self.vocab_size * self.d_model  # embed
         if not self.tie_embeddings:
             n += self.vocab_size * self.d_model  # lm head
-        n += self.num_layers * self._layer_params()
+        n += self.stack_layers * self._layer_params()
+        n += self.num_dense_layers * self._dense_layer_params()
         n += self.d_model  # final norm
         if self.encoder is not None:
             enc_layer = self._attn_params(self_only=True) + self._dense_ffn_params(self.d_ff) + 4 * self.d_model
@@ -154,7 +211,8 @@ class ModelConfig:
         n = self.vocab_size * self.d_model
         if not self.tie_embeddings:
             n += self.vocab_size * self.d_model
-        n += self.num_layers * self._layer_params(active=True)
+        n += self.stack_layers * self._layer_params(active=True)
+        n += self.num_dense_layers * self._dense_layer_params()
         return n
 
     # -- internals ------------------------------------------------------------
@@ -171,6 +229,7 @@ class ModelConfig:
             q = (self.d_model * a.q_lora_rank + a.q_lora_rank * a.num_heads * (a.qk_nope_head_dim + a.qk_rope_head_dim)
                  if a.q_lora_rank else self.d_model * a.num_heads * (a.qk_nope_head_dim + a.qk_rope_head_dim))
             kv = self.d_model * kv_in + a.kv_lora_rank * a.num_heads * (a.qk_nope_head_dim + a.v_head_dim)
+            kv += a.kv_lora_rank  # latent norm
             o = a.num_heads * a.v_head_dim * self.d_model
             return q + kv + o
         qkv = self.d_model * (a.num_heads + 2 * a.num_kv_heads) * a.head_dim
@@ -191,6 +250,9 @@ class ModelConfig:
         return (d * 2 * d_in + d_in * s.conv_width + d_in * (dt_rank + 2 * s.state_size)
                 + dt_rank * d_in + d_in * s.state_size + d_in + d_in * d)
 
+    def _dense_layer_params(self) -> int:
+        return 2 * self.d_model + self._attn_params() + self._dense_ffn_params(self.d_ff)
+
     def _layer_params(self, active: bool = False) -> int:
         n = 2 * self.d_model  # two norms
         n += self._attn_params()
@@ -198,7 +260,7 @@ class ModelConfig:
         if self.moe is not None:
             m = self.moe
             per_expert = self._dense_ffn_params(m.d_expert_hidden)
-            n_experts = (m.top_k if active else m.num_experts) + m.num_shared_experts
+            n_experts = (m.top_k if active else m.held) + m.num_shared_experts
             n += n_experts * per_expert
             n += self.d_model * m.num_experts  # router
             if m.dense_residual:
@@ -253,8 +315,11 @@ def reduced(cfg: ModelConfig, *, num_layers: int = 2, d_model: int = 256,
         ssm = dataclasses.replace(ssm, head_dim=min(ssm.head_dim, 32), lora_rank=16)
     moe = cfg.moe
     if moe is not None:
+        experts = min(moe.num_experts, max_experts)
+        held = (max(1, moe.experts_held * experts // moe.num_experts)
+                if moe.experts_held else 0)
         moe = dataclasses.replace(
-            moe, num_experts=min(moe.num_experts, max_experts),
+            moe, num_experts=experts, experts_held=held,
             top_k=min(moe.top_k, 2),
             d_expert_hidden=max(32, int(moe.d_expert_hidden * scale) // 8 * 8),
             num_shared_experts=min(moe.num_shared_experts, 1))
@@ -262,6 +327,7 @@ def reduced(cfg: ModelConfig, *, num_layers: int = 2, d_model: int = 256,
         cfg,
         name=cfg.name + "-reduced",
         num_layers=num_layers,
+        num_dense_layers=min(cfg.num_dense_layers, num_layers - 1),
         d_model=d_model,
         d_ff=max(64, int(cfg.d_ff * scale) // 8 * 8),
         vocab_size=min(cfg.vocab_size, 512),

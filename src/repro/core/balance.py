@@ -24,12 +24,16 @@ class MoEMetrics(NamedTuple):
     load: jax.Array  # (E,) float32 — fraction of tokens assigned per expert
     drop_frac: jax.Array  # scalar — fraction of (token, slot) pairs dropped
     obs: Any = None  # Optional[ObsCounters] — wire/drop/shadow counters
+    # scalar — fraction of (token, slot) assignments routed to experts the
+    # layer holds: experts_held / num_experts under even routing on a
+    # chip's share (MoEConfig.experts_held), 1 where every expert is held
+    held_frac: Any = None
 
     @staticmethod
     def zero(num_experts: int) -> "MoEMetrics":
         z = jnp.zeros(())
         return MoEMetrics(z, z, jnp.zeros((num_experts,)), z,
-                          ObsCounters.zero())
+                          ObsCounters.zero(), z)
 
     def __add__(self, other: "MoEMetrics") -> "MoEMetrics":
         return MoEMetrics(*(b if a is None else a if b is None else a + b

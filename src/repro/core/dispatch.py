@@ -80,6 +80,20 @@ def make_capacity_plan(expert_ids: jax.Array, num_experts: int,
 
 
 @scoped(DISPATCH)
+def hold_capacity_plan(plan: CapacityPlan, held: int):
+    """(plan, assignments to held experts): ``plan`` restricted to experts
+    ``0 .. held-1``, one chip's share of the layer.  The other experts'
+    assignments keep their slots in the uncut plan but are neither
+    dispatched nor combined here (position == capacity, keep False); the
+    capacity and each held expert's slots are the uncut layer's."""
+    on = plan.expert_ids < held
+    keep = plan.keep & on
+    return (plan._replace(positions=jnp.where(keep, plan.positions,
+                                              plan.capacity), keep=keep),
+            jnp.sum(on.astype(jnp.float32)))
+
+
+@scoped(DISPATCH)
 def dispatch_capacity(x: jax.Array, plan: CapacityPlan,
                       num_experts: int) -> jax.Array:
     """Scatter tokens (T, d) into per-expert buffers (E, C, d)."""

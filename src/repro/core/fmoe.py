@@ -30,7 +30,7 @@ from repro.core.gate import (expert_choice_forward, gate_forward, gate_init,
                              route_tokens, router_distill_loss, router_init)
 from repro.obs import counters as obs_counters
 from repro.obs.counters import ObsCounters
-from repro.obs.scopes import EXPERTS, ROUTER, scoped
+from repro.obs.scopes import DENSE_FFN, EXPERTS, ROUTER, scoped
 
 
 class DistConfig(NamedTuple):
@@ -172,8 +172,10 @@ def _act(h: jax.Array, act: str) -> jax.Array:
     return jax.nn.silu(h)  # swiglu gate handled by caller
 
 
+@scoped(DENSE_FFN)
 def dense_ffn(params: dict, x: jax.Array, act: str) -> jax.Array:
-    """Plain (non-expert) FFN on (..., d)."""
+    """Plain (non-expert) FFN on (..., d): a dense layer's FFN, the shared
+    experts, a dense residual."""
     if act == "swiglu":
         h = jax.nn.silu(x @ params["wi_gate"]) * (x @ params["wi_up"])
     else:
@@ -291,11 +293,12 @@ RAGGED_FNS: dict[str, Callable] = {
 
 def fmoe_init(rng: jax.Array, d_model: int, cfg: MoEConfig, *, act: str = "swiglu",
               d_ff_dense: int = 0, dtype=jnp.float32) -> dict:
-    """Parameters for one MoE FFN block."""
+    """Parameters for one MoE FFN block: the router over all
+    ``cfg.num_experts``, the ``cfg.held`` routed experts this chip holds."""
     ks = jax.random.split(rng, 4)
     params = {
         "router": router_init(ks[0], d_model, cfg, dtype=jnp.float32),
-        "experts": _ffn_init(ks[1], cfg.num_experts, d_model,
+        "experts": _ffn_init(ks[1], cfg.held, d_model,
                              cfg.d_expert_hidden, act, dtype),
     }
     if cfg.num_shared_experts:
@@ -379,6 +382,12 @@ def _moe_local(x: jax.Array, router: dict, experts: dict, cfg: MoEConfig,
                impl: str = "einsum", l2p=None):
     T = x.shape[0]
     table = _route_table(placement, l2p)
+    if cfg.held < cfg.num_experts and (
+            table is not None or cfg.dispatch != "capacity"
+            or cfg.router == "expert_choice"):
+        raise NotImplementedError(
+            f"a layer holding {cfg.held} of {cfg.num_experts} experts runs "
+            f"token-choice capacity dispatch without a placement")
     if cfg.router == "expert_choice":
         C, token_idx, ti_phys, ec_w, logits = _ec_route(router, x, cfg, table)
         E = cfg.num_experts
@@ -395,7 +404,8 @@ def _moe_local(x: jax.Array, router: dict, experts: dict, cfg: MoEConfig,
         y = D.combine_ec(out, token_idx, ec_w, T)
         metrics = MoEMetrics(jnp.zeros(()), router_z_loss(logits),
                              _ec_flat_load(E), jnp.zeros(()),
-                             obs_counters.local_counters(dropped=jnp.zeros(())))
+                             obs_counters.local_counters(dropped=jnp.zeros(())),
+                             jnp.ones(()))
         return y, metrics
     g = route_tokens(router, x, cfg, rng=rng)
     expert_ids = g.expert_ids
@@ -415,16 +425,25 @@ def _moe_local(x: jax.Array, router: dict, experts: dict, cfg: MoEConfig,
     else:
         C = D.expert_capacity(T, cfg.num_experts, cfg.top_k, cfg.capacity_factor)
         plan = D.make_capacity_plan(expert_ids, cfg.num_experts, C)
-        buf = D.dispatch_capacity(x, plan, cfg.num_experts)  # scatter (Fig 4)
+        if cfg.held < cfg.num_experts:
+            # one chip's share: capacity and slots as in the uncut layer,
+            # only the assignments to experts 0 .. held-1 dispatched
+            plan, on_held = D.hold_capacity_plan(plan, cfg.held)
+        buf = D.dispatch_capacity(x, plan, cfg.held)  # scatter (Fig 4)
         out = expert_fn(experts, buf, act)  # batched per-expert GeMM
         y = D.combine_capacity(out, plan, g.combine_weights)  # gather
         load, drop = load_metrics(plan.load, plan.keep, T * cfg.top_k)
+    assigned, held = T * cfg.top_k, jnp.ones(())
+    if cfg.held < cfg.num_experts:
+        # drops are counted among the assignments this chip received
+        assigned, held = on_held, on_held / (T * cfg.top_k)
+        drop = 1.0 - jnp.sum(plan.keep) / jnp.maximum(on_held, 1.0)
     if table is not None:
         load = load[table]  # logical order
     metrics = MoEMetrics(_aux_loss(router, x, g, cfg),
                          router_z_loss(g.logits), load, drop,
-                         obs_counters.local_counters(
-                             dropped=drop * (T * cfg.top_k)))
+                         obs_counters.local_counters(dropped=drop * assigned),
+                         held)
     return y, metrics
 
 
@@ -1211,6 +1230,11 @@ def fmoe_apply(params: dict, x: jax.Array, cfg: MoEConfig, *, act: str = "swiglu
         for k in residual_keys:
             y = y + dense_ffn(params[k], xf, act)
     else:
+        if cfg.held < cfg.num_experts:
+            raise NotImplementedError(
+                f"experts_held={cfg.experts_held} is one chip's share run "
+                f"without its exchange; the distributed paths spread all "
+                f"{cfg.num_experts} experts over the mesh")
         place = dist.placement if dist.placement is not None else placement
         if place is not None:
             _check_not_per_layer(place)
@@ -1316,4 +1340,6 @@ def fmoe_apply(params: dict, x: jax.Array, cfg: MoEConfig, *, act: str = "swiglu
         # paper-faithful baseline: residuals outside shard_map (auto-sharded)
         for k in residual_keys:
             y = y + dense_ffn(params[k], xf, act)
+        # the mesh holds every expert
+        metrics = metrics._replace(held_frac=jnp.ones(()))
     return y.reshape(shape), metrics

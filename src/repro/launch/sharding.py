@@ -220,11 +220,11 @@ def _flat_paths(tree, prefix=""):
         yield prefix[:-1], tree
 
 
-# A leaf is stacked by layer wherever a ``layers``/``enc_layers`` segment
-# sits in its path, so a tree that nests the params (AdamW's ``mu``/``nu``)
-# gets the params' own specs; a moment in another layout is resharded
-# inside every optimizer update.
-_STACKED = re.compile(r"(^|/)(enc_)?layers/")
+# A leaf is stacked by layer wherever a ``layers``/``enc_layers``/
+# ``dense_layers`` segment sits in its path, so a tree that nests the params
+# (AdamW's ``mu``/``nu``) gets the params' own specs; a moment in another
+# layout is resharded inside every optimizer update.
+_STACKED = re.compile(r"(^|/)(enc_|dense_)?layers/")
 
 
 def tree_specs(tree, mesh, mode: str = "train", cfg=None) -> Any:

@@ -166,8 +166,9 @@ def make_train_step(cfg: ModelConfig, opt: AdamW, *, dist=None,
             n_e = cfg.moe.num_experts if cfg.moe is not None else 1
             aux0 = {"ce": jnp.zeros(()), "aux_loss": jnp.zeros(()),
                     "z_loss": jnp.zeros(()), "drop_frac": jnp.zeros(()),
+                    "held_frac": jnp.zeros(()),
                     "load": jnp.zeros((n_e,)),
-                    "load_layers": jnp.zeros((cfg.num_layers, n_e)),
+                    "load_layers": jnp.zeros((cfg.stack_layers, n_e)),
                     # obs counters (repro.obs) emitted by loss_fn's aux
                     "wire_elems": jnp.zeros(()), "wire_bytes": jnp.zeros(()),
                     "wire_bytes_intra": jnp.zeros(()),
@@ -296,7 +297,7 @@ class ReplanHook:
         t_local = max(1, global_batch * seq_len // n_dev // num_microbatches)
         cap = expert_capacity(t_local, moe.num_experts, moe.top_k,
                               moe.capacity_factor)
-        L = cfg.num_layers if per_layer else 0
+        L = cfg.stack_layers if per_layer else 0
         self.sink = sink  # optional repro.obs MetricsSink (replan events +
         # the monitor's sampled load snapshots land here)
         # updates arrive pre-sampled (every sync_every steps), so record each

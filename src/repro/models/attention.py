@@ -10,7 +10,10 @@ Decode paths run one query against a ring-buffer KV cache (absolute positions
 stored alongside so RoPE is applied at write time and window/causal masks are
 position-exact).  MLA decode uses the *absorbed* form: only the compressed
 latent (kv_lora + rope_k) is cached and W_uk/W_uv are folded into the query /
-output projections — the memory advantage that motivates MLA.
+output projections — the memory advantage that motivates MLA.  The latent is
+RMS-normed before the up-projections (DeepSeek-V2's ``kv_a_layernorm``), so
+the cache holds the normed latent, and YaRN (``AttentionConfig.yarn``) sets
+the rope frequencies and the softmax scale.
 """
 from __future__ import annotations
 
@@ -20,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import AttentionConfig
-from repro.models.layers import apply_rope, linear, linear_init
+from repro.models.layers import (apply_norm, apply_rope, linear, linear_init,
+                                 norm_init)
 
 _NEG = -1e30
 
@@ -66,12 +70,19 @@ def _list_override(cell, value):
 
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         window: jax.Array | int, q_offset: int = 0,
-                        chunk: int | None = None, causal: bool = True) -> jax.Array:
-    """softmax(q k^T) v with online softmax over KV chunks.
+                        chunk: int | None = None, causal: bool = True,
+                        scale: float | None = None,
+                        recompute: bool = False) -> jax.Array:
+    """softmax(scale q k^T) v with online softmax over KV chunks.
 
     q: (B, Sq, H, dk); k: (B, Skv, KV, dk); v: (B, Skv, KV, dv).
     window: scalar — attend only to keys with 0 <= i - j < window (i absolute
     query pos = q_offset + row).  Pass Skv (or larger) for full attention.
+    scale: the softmax scale, dk ** -0.5 by default.
+    recompute: the backward pass recomputes each chunk's scores instead of
+    keeping them (the model's ``remat == "full"``): a scan saves its body's
+    residuals per step, which would materialize the full (Sq, Skv) scores
+    and probabilities.
     """
     B, Sq, H, dk = q.shape
     Skv_real, KV = k.shape[1], k.shape[2]
@@ -89,7 +100,7 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     sdt = SCORE_DTYPE[0]
     neg = _NEG if sdt == jnp.float32 else -3e38  # bf16 max ~3.39e38
     qg = q.reshape(B, Sq, KV, G, dk).astype(sdt)
-    scale = dk ** -0.5
+    scale = dk ** -0.5 if scale is None else scale
     i_pos = q_offset + jnp.arange(Sq)  # absolute query positions
     window = jnp.asarray(window, jnp.int32)
 
@@ -115,6 +126,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             preferred_element_type=jnp.float32)
         return (m_new, l, acc), None
 
+    if recompute and n_chunks > 1:
+        body = jax.checkpoint(body, prevent_cse=False)
     m0 = jnp.full((B, Sq, KV, G), _NEG, jnp.float32)
     l0 = jnp.zeros((B, Sq, KV, G), jnp.float32)
     a0 = jnp.zeros((B, Sq, KV, G, dv), jnp.float32)
@@ -172,10 +185,10 @@ def gqa_init(rng: jax.Array, d_model: int, cfg: AttentionConfig, dtype) -> dict:
 def gqa_apply(params: dict, x: jax.Array, cfg: AttentionConfig, *,
               window: jax.Array | int, positions: Optional[jax.Array] = None,
               kv_x: Optional[jax.Array] = None, causal: bool = True,
-              return_kv: bool = False):
+              return_kv: bool = False, recompute: bool = False):
     """Full-sequence GQA.  kv_x (cross-attention source) defaults to x.
     ``return_kv`` additionally returns the (post-RoPE) k, v for prefill
-    cache population."""
+    cache population; ``recompute`` as in :func:`blockwise_attention`."""
     B, S, _ = x.shape
     src = x if kv_x is None else kv_x
     Skv = src.shape[1]
@@ -186,7 +199,8 @@ def gqa_apply(params: dict, x: jax.Array, cfg: AttentionConfig, *,
         pos = jnp.arange(S) if positions is None else positions
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, jnp.arange(Skv), cfg.rope_theta)
-    out = blockwise_attention(q, k, v, window=window, causal=causal)
+    out = blockwise_attention(q, k, v, window=window, causal=causal,
+                              recompute=recompute)
     y = linear(params["wo"], out.reshape(B, S, -1))
     if return_kv:
         return y, (k, v)
@@ -243,7 +257,8 @@ def fill_kv_cache(cache: KVCache, k: jax.Array, v: jax.Array, *,
 
 def fill_mla_cache(cache: MLACache, ckv: jax.Array, kr: jax.Array, *,
                    start: int = 0) -> MLACache:
-    """Prefill the compressed-latent cache (ckv (B,S,lora), kr (B,S,rope))."""
+    """Prefill the compressed-latent cache (ckv (B,S,lora), the normed latent
+    ``mla_apply(return_kv=True)`` returns; kr (B,S,rope), rotated)."""
     B, S = ckv.shape[:2]
     W = cache.ckv.shape[1]
     tail = max(0, S - W)
@@ -367,11 +382,9 @@ def mla_decode_paged(params: dict, x: jax.Array, cache: PagedMLACache,
     bs = cache.ckv.shape[1]
     posb = _per_seq_pos(pos, B)
     q_nope, q_rope = _mla_q(params, x, cfg)
-    q_rope = apply_rope(q_rope, posb[:, None], cfg.rope_theta)
-
-    ckv = linear(params["w_dkv"], x)[:, 0]  # (B, lora)
-    kr = linear(params["w_kr"], x).reshape(B, 1, 1, cfg.qk_rope_head_dim)
-    kr = apply_rope(kr, posb[:, None], cfg.rope_theta)[:, 0, 0]
+    q_rope = apply_rope(q_rope, posb[:, None], cfg.rope_theta, cfg.yarn)
+    ckv, kr = _mla_latent(params, x, posb[:, None], cfg)
+    ckv, kr = ckv[:, 0], kr[:, 0, 0]  # (B, lora), (B, rope)
 
     pb, off = _paged_target(tables, posb, bs)
     new_cache = PagedMLACache(
@@ -388,7 +401,7 @@ def mla_decode_paged(params: dict, x: jax.Array, cache: PagedMLACache,
     s = jnp.einsum("bhr,bwr->bhw", q_eff, ckv_v.astype(jnp.float32))
     s += jnp.einsum("bhd,bwd->bhw", q_rope[:, 0].astype(jnp.float32),
                     kr_v.astype(jnp.float32))
-    s *= (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    s *= _mla_scale(cfg)
     dist = posb[:, None] - pos_v
     valid = (pos_v >= 0) & (dist >= 0) & (dist < jnp.asarray(window, jnp.int32))
     s = jnp.where(valid[:, None, :], s, _NEG)
@@ -423,6 +436,8 @@ def mla_init(rng: jax.Array, d_model: int, cfg: AttentionConfig, dtype) -> dict:
         "w_uv": (jax.random.normal(ks[3], (H, cfg.kv_lora_rank, cfg.v_head_dim))
                  * cfg.kv_lora_rank ** -0.5).astype(dtype),
         "wo": linear_init(ks[4], H * cfg.v_head_dim, d_model, dtype),
+        # RMSNorm on the compressed latent (kv_a_layernorm)
+        "kv_norm": norm_init(cfg.kv_lora_rank, "rmsnorm"),
     }
     if cfg.q_lora_rank:
         p["w_dq"] = linear_init(ks[5], d_model, cfg.q_lora_rank, dtype)
@@ -443,28 +458,44 @@ def _mla_q(params: dict, x: jax.Array, cfg: AttentionConfig):
     return jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)  # q_nope, q_rope
 
 
+def _mla_latent(params: dict, x: jax.Array, positions: jax.Array,
+                cfg: AttentionConfig):
+    """(ckv (B, S, lora), kr (B, S, 1, rope)): the normed compressed latent
+    and the rotated decoupled rope key — what the cache holds."""
+    B, S, _ = x.shape
+    ckv = apply_norm(params["kv_norm"], linear(params["w_dkv"], x), "rmsnorm")
+    kr = linear(params["w_kr"], x).reshape(B, S, 1, cfg.qk_rope_head_dim)
+    return ckv, apply_rope(kr, positions, cfg.rope_theta, cfg.yarn)
+
+
+def _mla_scale(cfg: AttentionConfig) -> float:
+    """Softmax scale over the (nope + rope)-wide query-key product."""
+    return ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+            * cfg.softmax_scale_factor)
+
+
 def mla_apply(params: dict, x: jax.Array, cfg: AttentionConfig, *,
               window: jax.Array | int,
               positions: Optional[jax.Array] = None,
-              return_kv: bool = False):
+              return_kv: bool = False, recompute: bool = False):
     """Training/prefill MLA: materialize per-head K/V from the latent and run
     blockwise attention on concat(nope, rope) keys.  ``return_kv`` returns
-    the compressed (ckv, kr) latents for prefill cache population."""
+    the compressed (ckv, kr) latents for prefill cache population;
+    ``recompute`` as in :func:`blockwise_attention`."""
     B, S, _ = x.shape
     H = cfg.num_heads
     q_nope, q_rope = _mla_q(params, x, cfg)
     pos = jnp.arange(S) if positions is None else positions
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, pos, cfg.rope_theta, cfg.yarn)
 
-    ckv = linear(params["w_dkv"], x)  # (B, S, lora)
-    kr = linear(params["w_kr"], x).reshape(B, S, 1, cfg.qk_rope_head_dim)
-    kr = apply_rope(kr, jnp.arange(S), cfg.rope_theta)
+    ckv, kr = _mla_latent(params, x, jnp.arange(S), cfg)
     k_nope = jnp.einsum("bsr,hrd->bshd", ckv, params["w_uk"])
     v = jnp.einsum("bsr,hrd->bshd", ckv, params["w_uv"])
 
     q = jnp.concatenate([q_nope, q_rope], -1)  # (B,S,H,nope+rope)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(kr, (B, S, H, cfg.qk_rope_head_dim))], -1)
-    out = blockwise_attention(q, k, v, window=window)
+    out = blockwise_attention(q, k, v, window=window, scale=_mla_scale(cfg),
+                              recompute=recompute)
     y = linear(params["wo"], out.reshape(B, S, -1))
     if return_kv:
         return y, (ckv, kr[:, :, 0, :])
@@ -480,11 +511,9 @@ def mla_decode(params: dict, x: jax.Array, cache: MLACache, pos: jax.Array,
     W = cache.ckv.shape[1]
     posb = _per_seq_pos(pos, B)
     q_nope, q_rope = _mla_q(params, x, cfg)  # (B,1,H,*)
-    q_rope = apply_rope(q_rope, posb[:, None], cfg.rope_theta)
-
-    ckv = linear(params["w_dkv"], x)[:, 0]  # (B, lora)
-    kr = linear(params["w_kr"], x).reshape(B, 1, 1, cfg.qk_rope_head_dim)
-    kr = apply_rope(kr, posb[:, None], cfg.rope_theta)[:, 0, 0]  # (B, rope)
+    q_rope = apply_rope(q_rope, posb[:, None], cfg.rope_theta, cfg.yarn)
+    ckv, kr = _mla_latent(params, x, posb[:, None], cfg)
+    ckv, kr = ckv[:, 0], kr[:, 0, 0]  # (B, lora), (B, rope)
 
     slots = posb % W
     bidx = jnp.arange(B)
@@ -498,7 +527,7 @@ def mla_decode(params: dict, x: jax.Array, cache: MLACache, pos: jax.Array,
     s = jnp.einsum("bhr,bwr->bhw", q_eff, cache.ckv.astype(jnp.float32))
     s += jnp.einsum("bhd,bwd->bhw", q_rope[:, 0].astype(jnp.float32),
                     cache.kr.astype(jnp.float32))
-    s *= (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    s *= _mla_scale(cfg)
     dist = posb[:, None] - cache.positions
     valid = (cache.positions >= 0) & (dist >= 0) & (dist < jnp.asarray(window, jnp.int32))
     s = jnp.where(valid[:, None, :], s, _NEG)

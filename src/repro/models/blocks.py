@@ -100,15 +100,16 @@ def _mixer_seq(p: dict, cfg: ModelConfig, x: jax.Array, window,
     if cfg.family == "ssm":
         return R.time_mix(p["rwkv"], x, state, cfg)
     a = cfg.attention
+    rc = cfg.remat == "full"  # the backward recomputes each chunk's scores
     if cfg.family == "hybrid":
-        y_a = A.gqa_apply(p["attn"], x, a, window=window)
+        y_a = A.gqa_apply(p["attn"], x, a, window=window, recompute=rc)
         y_m, mstate = M.mamba_apply(p["mamba"], x, state, cfg.ssm)
         y = 0.5 * (apply_norm(p["norm_a"], y_a, cfg.norm)
                    + apply_norm(p["norm_m"], y_m, cfg.norm))
         return y, mstate
     if a.kind == "mla":
-        return A.mla_apply(p["attn"], x, a, window=window), None
-    return A.gqa_apply(p["attn"], x, a, window=window), None
+        return A.mla_apply(p["attn"], x, a, window=window, recompute=rc), None
+    return A.gqa_apply(p["attn"], x, a, window=window, recompute=rc), None
 
 
 @scoped(ATTENTION)

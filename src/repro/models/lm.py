@@ -11,10 +11,13 @@ The layer stack is stored stacked (leading L dim on every leaf) and applied
 with jax.lax.scan (+ jax.remat per layer when cfg.remat == "full") — essential
 for compile time at 80 layers x 512 devices.  Per-layer sliding windows ride
 along as a scanned (L,) array so Hymba's global layers coexist with windowed
-ones inside one homogeneous scan.
+ones inside one homogeneous scan.  Leading dense-FFN layers
+(``cfg.num_dense_layers``, DeepSeek's first_k_dense_replace) are a stack of
+their own, ``dense_layers``, scanned before the ``layers`` stack.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Optional
 
@@ -53,10 +56,10 @@ def _layer_tables(cfg: ModelConfig, dist: Optional[DistConfig]):
     if not isinstance(place, PerLayerPlacement):
         return dist, None
     place.validate()
-    if place.num_layers != cfg.num_layers:
+    if place.num_layers != cfg.stack_layers:
         raise ValueError(
             f"per-layer placement has {place.num_layers} layers, "
-            f"config has {cfg.num_layers}")
+            f"config has {cfg.stack_layers} MoE layers")
     tables = jnp.asarray(place.logical_to_physical, jnp.int32)  # (L, E)
     return dist._replace(placement=place.geometry), tables
 
@@ -75,15 +78,24 @@ def _stacked_layer_init(rng: jax.Array, cfg: ModelConfig, n: int,
     return jax.vmap(lambda k: B.layer_init(k, cfg, cross=cross))(keys)
 
 
+def _dense_cfg(cfg: ModelConfig) -> ModelConfig:
+    """The config a leading dense layer runs: the model's, FFN of width
+    ``d_ff`` in place of the MoE."""
+    return dataclasses.replace(cfg, moe=None)
+
+
 def init_params(rng: jax.Array, cfg: ModelConfig) -> dict:
     ks = jax.random.split(rng, 6)
     dtype = jnp.dtype(cfg.param_dtype)
     p: dict = {
         "embed": embed_init(ks[0], cfg.vocab_size, cfg.d_model, dtype),
-        "layers": _stacked_layer_init(ks[1], cfg, cfg.num_layers,
+        "layers": _stacked_layer_init(ks[1], cfg, cfg.stack_layers,
                                       cross=cfg.family == "audio"),
         "final_norm": norm_init(cfg.d_model, cfg.norm),
     }
+    if cfg.num_dense_layers:
+        p["dense_layers"] = _stacked_layer_init(ks[4], _dense_cfg(cfg),
+                                                cfg.num_dense_layers)
     if not cfg.tie_embeddings:
         p["lm_head"] = linear_init(ks[2], cfg.d_model, cfg.vocab_size, dtype)
     if cfg.encoder is not None:
@@ -156,6 +168,10 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
 
     batch = x.shape[0]
     windows = B.layer_windows(cfg)
+    if cfg.num_dense_layers:
+        x = _dense_stack(params["dense_layers"], cfg, x,
+                         windows[:cfg.num_dense_layers], dist)
+        windows = windows[cfg.num_dense_layers:]
     state0 = B.mixer_state(cfg, batch, dtype)
     n_e = _n_experts(cfg)
     want_loads = layer_loads and cfg.moe is not None
@@ -181,15 +197,34 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     if tables is not None:
         xs += (tables,)
     if has_rng:
-        xs += (jax.random.split(rng, cfg.num_layers),)
+        xs += (jax.random.split(rng, cfg.stack_layers),)
     (x, metrics), loads = jax.lax.scan(body, (x, MoEMetrics.zero(n_e)), xs)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     logits = _logits(params, cfg, x)
     if layer_loads:
         if loads is None:
-            loads = jnp.zeros((cfg.num_layers, n_e))
+            loads = jnp.zeros((cfg.stack_layers, n_e))
         return logits, metrics, loads
     return logits, metrics
+
+
+def _dense_stack(params: dict, cfg: ModelConfig, x: jax.Array,
+                 windows: jax.Array, dist: Optional[DistConfig]) -> jax.Array:
+    """The leading dense layers over x (B, S, d), scanned like the MoE
+    stack."""
+    dtype = jnp.dtype(cfg.dtype)
+    dcfg = _dense_cfg(cfg)
+
+    def body(x, xs):
+        p_l, window = xs
+        x, _ = B.layer_apply_seq(_cast_params(p_l, dtype), dcfg, x,
+                                 window=window, dist=dist)
+        return x.astype(dtype), None
+
+    if cfg.remat == "full":
+        body = jax.remat(body)
+    x, _ = jax.lax.scan(body, x, (params, windows))
+    return x
 
 
 @scoped(HEAD_LOSS)
@@ -216,13 +251,13 @@ def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *,
         logits = logits[:, batch["patches"].shape[1]:]  # text positions only
     ce = _next_token_ce(logits, tokens)
     loss = ce
+    L = max(cfg.stack_layers, 1)  # the MoE losses average over MoE layers
     if cfg.moe is not None:
-        L = cfg.num_layers
         loss = loss + (cfg.moe.balance_loss_weight * metrics.aux_loss
                        + cfg.moe.z_loss_weight * metrics.z_loss) / L
-    L = max(cfg.num_layers, 1)
     aux = {"ce": ce, "aux_loss": metrics.aux_loss, "z_loss": metrics.z_loss,
            "drop_frac": metrics.drop_frac / L,
+           "held_frac": metrics.held_frac / L,
            "load": metrics.load / L,  # per-expert load for the §6 monitor
            "load_layers": loads}  # (L, E) per-layer load (per-layer planner)
     obs = metrics.obs
@@ -248,6 +283,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array, cache: Any, *,
             dist: Optional[DistConfig] = None, impl: str = "einsum"):
     """tokens (B, S) + empty cache -> (logits (B, S', V), filled cache,
     metrics).  Decoding then continues at position S' with decode_step."""
+    _check_no_dense_layers(cfg, "prefill")
     dtype = jnp.dtype(cfg.dtype)
     dist, tables = _layer_tables(cfg, dist)
     x = embed_lookup(params["embed"], tokens, dtype)
@@ -290,10 +326,20 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array, cache: Any, *,
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int, *,
                enc_out: Optional[jax.Array] = None) -> Any:
     """Stacked (leading L dim) decode cache for the layer stack."""
+    _check_no_dense_layers(cfg, "a decode cache")
     dtype = jnp.dtype(cfg.dtype)
     one = B.layer_cache(cfg, batch, cache_len, dtype, enc_out=enc_out)
     L = cfg.num_layers
     return jax.tree.map(lambda a: jnp.broadcast_to(a[None], (L,) + a.shape), one)
+
+
+def _check_no_dense_layers(cfg: ModelConfig, what: str) -> None:
+    """Serving covers one homogeneous layer stack: leading dense layers
+    have no decode cache yet."""
+    if cfg.num_dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: {what} is not supported for a model with "
+            f"{cfg.num_dense_layers} leading dense layer(s); it trains only")
 
 
 def supports_paged(cfg: ModelConfig) -> bool:
@@ -313,6 +359,7 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int,
     if not supports_paged(cfg):
         raise NotImplementedError(
             f"paged KV cache is not supported for family {cfg.family!r}")
+    _check_no_dense_layers(cfg, "a paged decode cache")
     dtype = jnp.dtype(cfg.dtype)
     one = B.layer_paged_cache(cfg, num_blocks, block_size, dtype)
     L = cfg.num_layers
@@ -335,6 +382,7 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     True`` additionally returns the (L, E) per-layer expert-load stack as a
     fourth output — the online serve-time replan feed (mirrors
     ``forward(layer_loads=True)``)."""
+    _check_no_dense_layers(cfg, "decode")
     dtype = jnp.dtype(cfg.dtype)
     dist, tables = _layer_tables(cfg, dist)
     x = embed_lookup(params["embed"], tokens, dtype)

@@ -19,6 +19,12 @@ line of its own:
 ``fmoe.optimizer``  ``AdamW.update``, global-norm clip included
 ==================  =====================================================
 
+One more name, ``fmoe.dense_ffn`` (``core.fmoe.dense_ffn``), marks the FFNs
+every token runs: leading dense layers, shared experts, dense residuals and
+dense models' FFNs.  It stands outside ``ALL`` because the eight stages are
+in every MoE train step and it is not; ``bench/scopes.py`` leaves it
+unscoped, and a reader that wants it builds its own table.
+
 A scope is compile-time metadata: it sets the ``op_name`` of the HLO
 instructions traced inside it and adds no operation, collective or host
 work.  JAX carries the name stack through ``jvp``/``transpose`` and remat,
@@ -45,6 +51,7 @@ OPTIMIZER = "fmoe.optimizer"
 
 ALL = (ROUTER, DISPATCH, EXCHANGE, EXPERTS, COMBINE, ATTENTION, HEAD_LOSS,
        OPTIMIZER)
+DENSE_FFN = "fmoe.dense_ffn"
 
 
 def scope(name: str):
